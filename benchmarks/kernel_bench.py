@@ -96,9 +96,13 @@ def decode_attention_ablation(contexts=(256, 512, 1024), page=16):
 
     b, hq, hkv, d = 2, 8, 2, 64
 
+    def dense(pool, pg):
+        """gather_pages' (b, t, hkv * d) rows as (b, t, hkv, d)."""
+        return cm.gather_pages(pool, pg).reshape(b, -1, hkv, d)
+
     def xla_path(q, kp, vp, pg, ln):
-        kd = cm.gather_pages(kp, pg)
-        vd = cm.gather_pages(vp, pg)
+        kd = dense(kp, pg)
+        vd = dense(vp, pg)
         return cm._sdpa(q[:, None], kd, vd, causal=True, q_offset=ln - 1,
                         kv_len=ln)[:, 0]
 
@@ -110,9 +114,9 @@ def decode_attention_ablation(contexts=(256, 512, 1024), page=16):
         n_pages = b * P
         ks = jax.random.split(jax.random.PRNGKey(t), 4)
         q = jax.random.normal(ks[0], (b, hq, d), jnp.float32)
-        k_pool = jax.random.normal(ks[1], (n_pages, page, hkv, d),
+        k_pool = jax.random.normal(ks[1], (n_pages, page, hkv * d),
                                    jnp.float32)
-        v_pool = jax.random.normal(ks[2], (n_pages, page, hkv, d),
+        v_pool = jax.random.normal(ks[2], (n_pages, page, hkv * d),
                                    jnp.float32)
         perm = jax.random.permutation(ks[3], n_pages)[: b * P]
         pages = perm.reshape(b, P).astype(jnp.int32)
@@ -120,8 +124,8 @@ def decode_attention_ablation(contexts=(256, 512, 1024), page=16):
         splits = ops.plan_splits(t, page)
 
         def gather_kernel(q, kp, vp, pg, ln, s=splits):
-            kd = jnp.swapaxes(cm.gather_pages(kp, pg), 1, 2)
-            vd = jnp.swapaxes(cm.gather_pages(vp, pg), 1, 2)
+            kd = jnp.swapaxes(dense(kp, pg), 1, 2)
+            vd = jnp.swapaxes(dense(vp, pg), 1, 2)
             return ops.decode_attention(q, kd, vd, ln, bkv=page, splits=s)
 
         def paged_kernel(q, kp, vp, pg, ln, s=splits):

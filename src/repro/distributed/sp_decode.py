@@ -13,8 +13,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.decode_attention import ref as dec_ref
 
@@ -67,7 +66,7 @@ def sp_decode_attention(
         return (num / jnp.maximum(den, 1e-30)[..., None]).astype(q_.dtype)
 
     spec_kv = P(None, None, axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), spec_kv, spec_kv, P()),
         out_specs=P(),

@@ -1,8 +1,9 @@
-"""Hardware model constants for the TARGET platform (TPU v5e) and roofline math.
+"""Hardware model constants for the target platform (TPU v5e) and roofline math.
 
-This container executes on CPU; these constants define the machine the
-framework is designed for and drive the analytical cost model, the VMEM
-allocator and the roofline analysis of the dry-run artifacts.
+These constants describe the chip the framework runs on and drive the
+analytical cost model, the VMEM allocator, the kernels' scoped-VMEM limit and
+the roofline analysis of the dry-run artifacts.  ``chip_for_device`` maps a
+JAX device to its entry; a TPU that no entry describes is an error.
 """
 from __future__ import annotations
 
@@ -43,6 +44,25 @@ class Chip:
 
 
 V5E = Chip()
+
+# JAX ``device_kind`` -> chip model.  Only chips described here may run the
+# kernels or be planned for; CPU processes (tests in interpret mode, and
+# compile-only rehearsals against a described v5e topology) plan for the
+# v5e, the chip this framework targets.
+CHIPS_BY_KIND = {"TPU v5 lite": V5E}
+
+
+def chip_for_device(device) -> Chip:
+    """The :class:`Chip` entry for a JAX device (``jax.devices()[0]``)."""
+    if device.platform == "cpu":
+        return V5E
+    try:
+        return CHIPS_BY_KIND[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hw.Chip entry describes {device.platform} device kind "
+            f"{device.device_kind!r}; add it to hw.CHIPS_BY_KIND"
+        ) from None
 
 # Calibrated model of the paper's simulated system (Table 1): 64-CU GCN3 APU,
 # ~12.3 TFLOP/s fp32, HBM2 @ 512 GB/s, 4 MB GPU L2 (the "cache capacity" that

@@ -21,20 +21,37 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import NEG_INF, cdiv, interpret_default
+from repro.kernels.common import (
+    NEG_INF, cdiv, compiler_params, interpret_mode,
+)
+
+# Grid (b, splits, kv_steps): every axis but the KV sweep is parallel.
+_SEMANTICS = ("parallel", "parallel", "arbitrary")
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _decode_kernel(
-    q_ref, k_ref, v_ref, len_ref,
-    acc_out, m_out, l_out,
-    acc_ref, m_ref, l_ref,
-    *,
-    bkv: int,
-    kv_steps: int,
-    scale: float,
-):
-    s_idx = pl.program_id(2)   # split index
-    ik = pl.program_id(3)      # kv block within split
+def _decode_kernel(*refs, n_prefetch: int, group: int, hkv: int, d: int,
+                   bkv: int, kv_steps: int, scale: float):
+    """One (slot, split) accumulator over ``kv_steps`` KV blocks.
+
+    A block holds every head of ``bkv`` positions as (bkv, hkv * d) rows —
+    one page of the paged pool, or ``bkv`` rows of the dense view — so its
+    last two dims are whole array dims for any head count or width.  Head
+    h's scores sum lanes [h*d, (h+1)*d) of ``q * k``; that segmented sum,
+    and the broadcast of per-head weights back over those lanes, are
+    matmuls with a 0/1 head-indicator matrix at full f32 precision.
+
+    Shared block for block by the dense and the paged kernels: only the
+    index maps that choose each K/V block differ, so with ``bkv ==
+    page_size`` and equal splits the two are bit-identical.  The per-slot
+    ``lengths`` ride in as the last scalar-prefetch operand (SMEM)."""
+    len_ref = refs[n_prefetch - 1]
+    (q_ref, k_ref, v_ref, acc_out, m_out, l_out,
+     acc_ref, m_ref, l_ref) = refs[n_prefetch:]
+    ib = pl.program_id(0)
+    s_idx = pl.program_id(1)   # split index
+    ik = pl.program_id(2)      # kv block within split
+    hd = hkv * d
 
     @pl.when(ik == 0)
     def _init():
@@ -42,32 +59,95 @@ def _decode_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    valid_len = len_ref[0]
+    # seg[i, h] = 1 iff lane i belongs to head h; bcast is its transpose.
+    seg = (jax.lax.broadcasted_iota(jnp.int32, (hd, hkv), 0) // d
+           == jax.lax.broadcasted_iota(jnp.int32, (hd, hkv), 1)
+           ).astype(jnp.float32)
+    bcast = (jax.lax.broadcasted_iota(jnp.int32, (hkv, hd), 1) // d
+             == jax.lax.broadcasted_iota(jnp.int32, (hkv, hd), 0)
+             ).astype(jnp.float32)
+    k = k_ref[...].astype(jnp.float32)                   # (bkv, hd)
+    v = v_ref[...].astype(jnp.float32)                   # (bkv, hd)
     base = (s_idx * kv_steps + ik) * bkv
-    pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1)[0]
-    mask = pos < valid_len
+    pos = base + jax.lax.broadcasted_iota(jnp.int32, (bkv, hkv), 0)
+    mask = pos < len_ref[ib]                             # (bkv, hkv)
 
-    q = q_ref[0].astype(jnp.float32)                    # (hq, d)
-    k = k_ref[0, 0].astype(jnp.float32)                 # (bkv, d)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (hq, bkv)
-    s = jnp.where(mask[None, :], s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.where(mask[None, :], jnp.exp(s - m_cur[:, None]), 0.0)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-    v = v_ref[0, 0].astype(jnp.float32)                 # (bkv, d)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
-        p, v, preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_cur
+    for g in range(group):     # query heads h * group + g, h = 0..hkv-1
+        row = pl.ds(g, 1)
+        qg = q_ref[row, :].astype(jnp.float32)           # (1, hd)
+        s = jnp.dot(qg * k, seg, precision=_HIGHEST,
+                    preferred_element_type=jnp.float32) * scale
+        s = jnp.where(mask, s, NEG_INF)                  # (bkv, hkv)
+        m_prev = m_ref[row, :]                           # (1, hkv)
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
+        l_ref[row, :] = l_ref[row, :] * alpha + jnp.sum(p, axis=0,
+                                                        keepdims=True)
+        pv = jnp.dot(p, bcast, precision=_HIGHEST,
+                     preferred_element_type=jnp.float32) * v
+        acc_ref[row, :] = acc_ref[row, :] * jnp.dot(
+            alpha, bcast, precision=_HIGHEST,
+            preferred_element_type=jnp.float32,
+        ) + jnp.sum(pv, axis=0, keepdims=True)
+        m_ref[row, :] = m_cur
 
     @pl.when(ik == kv_steps - 1)
     def _flush():
-        acc_out[0, :, 0, :] = acc_ref[...]
-        m_out[0, :, 0] = m_ref[...]
-        l_out[0, :, 0] = l_ref[...]
+        acc_out[...] = acc_ref[...]
+        m_out[...] = m_ref[...]
+        l_out[...] = l_ref[...]
+
+
+def _partials_call(prefetch, q, k, v, kv_spec, *, hkv, splits, kv_steps,
+                   bkv, scale, interpret):
+    """pallas_call shared by both kernels over grid (b, splits, kv_steps).
+
+    q goes in as (b, group, hkv * d) — row g holds query heads h * group +
+    g — and the partials come out as (b, splits, group, ·), returned in
+    ``combine_partials``' (b, hq, splits, ·) layout."""
+    b, hq, d = q.shape
+    group = hq // hkv
+    hd = hkv * d
+    qg = jnp.swapaxes(q.reshape(b, hkv, group, d), 1, 2).reshape(b, group, hd)
+
+    def out_spec(width):
+        return pl.BlockSpec((None, None, group, width),
+                            lambda ib, sp, ik, *_: (ib, sp, 0, 0))
+
+    acc, m, l = pl.pallas_call(
+        functools.partial(_decode_kernel, n_prefetch=len(prefetch),
+                          group=group, hkv=hkv, d=d, bkv=bkv,
+                          kv_steps=kv_steps, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(b, splits, kv_steps),
+            in_specs=[
+                pl.BlockSpec((None, group, hd),
+                             lambda ib, sp, ik, *_: (ib, 0, 0)),
+                kv_spec,
+                kv_spec,
+            ],
+            out_specs=[out_spec(hd), out_spec(hkv), out_spec(hkv)],
+            scratch_shapes=[
+                pltpu.VMEM((group, hd), jnp.float32),
+                pltpu.VMEM((group, hkv), jnp.float32),
+                pltpu.VMEM((group, hkv), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, splits, group, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, splits, group, hkv), jnp.float32),
+            jax.ShapeDtypeStruct((b, splits, group, hkv), jnp.float32),
+        ],
+        compiler_params=compiler_params(*_SEMANTICS),
+        interpret=interpret_mode(interpret),
+    )(*prefetch, qg, k, v)
+    # (b, splits, group, hkv[, d]) -> (b, hkv, group, splits[, d]) -> hq.
+    acc = acc.reshape(b, splits, group, hkv, d).transpose(0, 3, 2, 1, 4)
+    m, l = (x.transpose(0, 3, 2, 1) for x in (m, l))
+    return (acc.reshape(b, hq, splits, d), m.reshape(b, hq, splits),
+            l.reshape(b, hq, splits))
 
 
 @functools.partial(
@@ -87,9 +167,7 @@ def decode_attention(
     b, hq, d = q.shape
     _, hkv, s, _ = k.shape
     assert hq % hkv == 0
-    group = hq // hkv
     scale = float(scale if scale is not None else d ** -0.5)
-    interpret = interpret_default() if interpret is None else interpret
     if lengths is None:
         lengths = jnp.full((b,), s, jnp.int32)
 
@@ -97,50 +175,21 @@ def decode_attention(
     # Pad s so it divides evenly into splits * kv_steps * bkv.
     per_split = cdiv(cdiv(s, splits), bkv) * bkv
     s_pad = per_split * splits
-    if s_pad != s:
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, s_pad - s), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, s_pad - s), (0, 0)))
+    # Rows of all heads, (b, s_pad, hkv * d): the kernel's block layout.
+    k, v = (jnp.pad(jnp.swapaxes(x, 1, 2), ((0, 0), (0, s_pad - s),
+                                            (0, 0), (0, 0)))
+            .reshape(b, s_pad, hkv * d) for x in (k, v))
     kv_steps = per_split // bkv
 
-    grid = (b, hkv, splits, kv_steps)
-    acc, m, l = pl.pallas_call(
-        functools.partial(
-            _decode_kernel, bkv=bkv, kv_steps=kv_steps, scale=scale
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (1, group, d), lambda ib, ih, sp, ik, g=group: (ib, ih, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, bkv, d),
-                lambda ib, ih, sp, ik, ks=kv_steps: (ib, ih, sp * ks + ik, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, bkv, d),
-                lambda ib, ih, sp, ik, ks=kv_steps: (ib, ih, sp * ks + ik, 0),
-            ),
-            pl.BlockSpec((1,), lambda ib, ih, sp, ik: (ib,)),
-        ],
-        out_specs=[
-            pl.BlockSpec(
-                (1, group, 1, d), lambda ib, ih, sp, ik: (ib, ih, sp, 0)
-            ),
-            pl.BlockSpec((1, group, 1), lambda ib, ih, sp, ik: (ib, ih, sp)),
-            pl.BlockSpec((1, group, 1), lambda ib, ih, sp, ik: (ib, ih, sp)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, splits, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, splits), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, splits), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((group, d), jnp.float32),
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group,), jnp.float32),
-        ],
+    kv_spec = pl.BlockSpec(
+        (None, bkv, hkv * d),
+        lambda ib, sp, ik, ln, ks=kv_steps: (ib, sp * ks + ik, 0),
+    )
+    acc, m, l = _partials_call(
+        (lengths.astype(jnp.int32),), q, k, v, kv_spec, hkv=hkv,
+        splits=splits, kv_steps=kv_steps, bkv=bkv, scale=scale,
         interpret=interpret,
-    )(q, k, v, lengths.astype(jnp.int32))
+    )
     return combine_partials(acc, m, l).astype(q.dtype)
 
 
@@ -161,7 +210,7 @@ def combine_partials(
 # ---------------------------------------------------------------------------
 # Paged variant: dereference the page table inside the kernel.
 #
-# The paged engine's KV lives in a (N, page_size, hkv, d) pool addressed
+# The paged engine's KV lives in an (N, page_size, hkv * d) pool addressed
 # through per-slot page tables (models/common.py, DESIGN.md §5.2).  The
 # dense path pays ``gather_pages`` — an XLA copy of the whole resident
 # context — before every decode step.  Here the gather disappears: the page
@@ -174,67 +223,13 @@ def combine_partials(
 # ---------------------------------------------------------------------------
 
 
-def _paged_decode_kernel(
-    pages_ref, len_ref,            # scalar-prefetch: (b, P) table, (b,) lens
-    q_ref, k_ref, v_ref,
-    acc_out, m_out, l_out,
-    acc_ref, m_ref, l_ref,
-    *,
-    psz: int,
-    page_steps: int,
-    scale: float,
-):
-    ib = pl.program_id(0)
-    s_idx = pl.program_id(2)   # split index
-    ik = pl.program_id(3)      # page within split
-
-    @pl.when(ik == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    # Same mask as the dense kernel over the gathered view: logical page
-    # lp covers positions [lp*psz, (lp+1)*psz), valid below the slot's
-    # cursor.  Unmapped (-1) and grid-overrun pages were clamped by the
-    # index map; every lane they contribute sits at pos >= valid_len, so
-    # the mask zeroes them exactly (p == 0.0, alpha == 1.0) — the paged
-    # twin of gather_pages' clamp-to-page-0-then-mask contract.
-    valid_len = len_ref[ib]
-    base = (s_idx * page_steps + ik) * psz
-    pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, psz), 1)[0]
-    mask = pos < valid_len
-
-    q = q_ref[0].astype(jnp.float32)                    # (group, d)
-    k = k_ref[...].astype(jnp.float32)[0, :, 0]         # (psz, d)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    s = jnp.where(mask[None, :], s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.where(mask[None, :], jnp.exp(s - m_cur[:, None]), 0.0)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-    v = v_ref[...].astype(jnp.float32)[0, :, 0]         # (psz, d)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
-        p, v, preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_cur
-
-    @pl.when(ik == page_steps - 1)
-    def _flush():
-        acc_out[0, :, 0, :] = acc_ref[...]
-        m_out[0, :, 0] = m_ref[...]
-        l_out[0, :, 0] = l_ref[...]
-
-
 @functools.partial(
     jax.jit, static_argnames=("scale", "splits", "interpret")
 )
 def paged_decode_attention(
     q: jnp.ndarray,          # (b, hq, d)
-    k_pool: jnp.ndarray,     # (N, page_size, hkv, d) physical page pool
-    v_pool: jnp.ndarray,     # (N, page_size, hkv, d)
+    k_pool: jnp.ndarray,     # (N, page_size, hkv * d) physical page pool
+    v_pool: jnp.ndarray,     # (N, page_size, hkv * d)
     pages: jnp.ndarray,      # (b, P) int32 page table, -1 = unmapped
     lengths: jnp.ndarray | None = None,   # (b,) valid lengths, <= P*psz
     *,
@@ -243,12 +238,11 @@ def paged_decode_attention(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     b, hq, d = q.shape
-    N, psz, hkv, _ = k_pool.shape
+    N, psz, hd = k_pool.shape
+    hkv = hd // d
     P = pages.shape[1]
-    assert hq % hkv == 0
-    group = hq // hkv
+    assert hq % hkv == 0 and hkv * d == hd
     scale = float(scale if scale is not None else d ** -0.5)
-    interpret = interpret_default() if interpret is None else interpret
     if lengths is None:
         lengths = jnp.full((b,), P * psz, jnp.int32)
 
@@ -258,59 +252,23 @@ def paged_decode_attention(
     # the kernel — an exact no-op, same as the dense kernel's zero padding.
     splits = max(1, min(int(splits), P))
     page_steps = cdiv(P, splits)
-    grid = (b, hkv, splits, page_steps)
 
     # Index maps get the grid indices plus the scalar-prefetch refs; the
     # K/V maps dereference the table (clamping unmapped entries to page 0,
     # mirroring gather_pages) so only the referenced page is ever pulled
-    # from HBM — no dense per-slot copy exists anywhere.
+    # from HBM — no dense per-slot copy exists anywhere.  Unmapped (-1) and
+    # grid-overrun pages contribute lanes at pos >= valid_len only, which
+    # the kernel's length mask zeroes exactly (p == 0.0, alpha == 1.0).
     kv_spec = pl.BlockSpec(
-        (1, psz, 1, d),
-        lambda ib, ih, sp, ik, pt, ln, ps=page_steps, Pn=P, Nn=N: (
+        (None, psz, hd),
+        lambda ib, sp, ik, pt, ln, ps=page_steps, Pn=P, Nn=N: (
             jnp.clip(pt[ib, jnp.minimum(sp * ps + ik, Pn - 1)], 0, Nn - 1),
-            0, ih, 0,
+            0, 0,
         ),
     )
-    acc, m, l = pl.pallas_call(
-        functools.partial(
-            _paged_decode_kernel, psz=psz, page_steps=page_steps, scale=scale
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec(
-                    (1, group, d),
-                    lambda ib, ih, sp, ik, pt, ln: (ib, ih, 0),
-                ),
-                kv_spec,
-                kv_spec,
-            ],
-            out_specs=[
-                pl.BlockSpec(
-                    (1, group, 1, d),
-                    lambda ib, ih, sp, ik, pt, ln: (ib, ih, sp, 0),
-                ),
-                pl.BlockSpec(
-                    (1, group, 1),
-                    lambda ib, ih, sp, ik, pt, ln: (ib, ih, sp),
-                ),
-                pl.BlockSpec(
-                    (1, group, 1),
-                    lambda ib, ih, sp, ik, pt, ln: (ib, ih, sp),
-                ),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((group, d), jnp.float32),
-                pltpu.VMEM((group,), jnp.float32),
-                pltpu.VMEM((group,), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, splits, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, splits), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, splits), jnp.float32),
-        ],
-        interpret=interpret,
-    )(pages.astype(jnp.int32), lengths.astype(jnp.int32), q, k_pool, v_pool)
+    acc, m, l = _partials_call(
+        (pages.astype(jnp.int32), lengths.astype(jnp.int32)), q, k_pool,
+        v_pool, kv_spec, hkv=hkv, splits=splits, kv_steps=page_steps,
+        bkv=psz, scale=scale, interpret=interpret,
+    )
     return combine_partials(acc, m, l).astype(q.dtype)

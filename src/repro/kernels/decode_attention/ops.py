@@ -14,7 +14,7 @@ import jax.numpy as jnp
 
 from repro.core import CachePolicyEngine
 from repro.core.characterize import attention_op
-from repro.kernels.common import cdiv, interpret_default
+from repro.kernels.common import cdiv
 
 
 def plan_splits(
@@ -63,7 +63,6 @@ def decode_attention(
         decode_attention as _kernel,
     )
 
-    interpret = interpret_default() if interpret is None else interpret
     s = k.shape[2]
     bkv = bkv or 512
     if splits is None:
@@ -81,8 +80,8 @@ def decode_attention(
 
 def paged_decode_attention(
     q: jnp.ndarray,          # (b, hq, d)
-    k_pool: jnp.ndarray,     # (N, page_size, hkv, d)
-    v_pool: jnp.ndarray,     # (N, page_size, hkv, d)
+    k_pool: jnp.ndarray,     # (N, page_size, hkv * d)
+    v_pool: jnp.ndarray,     # (N, page_size, hkv * d)
     pages: jnp.ndarray,      # (b, P) int32, -1 = unmapped
     lengths: jnp.ndarray | None = None,
     *,
@@ -102,15 +101,14 @@ def paged_decode_attention(
         paged_decode_attention as _kernel,
     )
 
-    interpret = interpret_default() if interpret is None else interpret
     psz = k_pool.shape[1]
     P = pages.shape[1]
     if splits is None:
         plan = None
         if engine is not None:
             plan = _engine_plan(
-                engine, q.shape[0], q.shape[1], k_pool.shape[2],
-                P * psz, q.shape[2],
+                engine, q.shape[0], q.shape[1],
+                k_pool.shape[2] // q.shape[2], P * psz, q.shape[2],
             )
         splits = plan_splits(P * psz, psz, plan=plan)
     return _kernel(

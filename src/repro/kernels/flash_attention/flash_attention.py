@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import NEG_INF, cdiv
+from repro.kernels.common import NEG_INF, cdiv, compiler_params, interpret_mode
 
 
 def _flash_kernel(
@@ -105,7 +105,7 @@ def flash_attention(
     bq: int = 256,
     bkv: int = 256,
     q_offset: int = 0,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
@@ -151,6 +151,7 @@ def flash_attention(
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
         ],
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=interpret_mode(interpret),
     )(q, k, v)
     return out[:, :, :sq, :]

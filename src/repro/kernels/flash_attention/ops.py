@@ -5,7 +5,6 @@ import jax.numpy as jnp
 
 from repro.core import CachePolicyEngine
 from repro.core.characterize import attention_op
-from repro.kernels.common import interpret_default
 from repro.kernels.flash_attention.flash_attention import flash_attention as _kernel
 
 
@@ -24,7 +23,6 @@ def flash_attention(
 ) -> jnp.ndarray:
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
-    interpret = interpret_default() if interpret is None else interpret
     if engine is not None and (bq is None or bkv is None):
         plan = engine.plan_op(
             attention_op(b, hq, hkv, sq, skv, d, causal=causal, dtype=str(q.dtype))

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import cdiv
+from repro.kernels.common import cdiv, compiler_params, interpret_mode
 
 
 def _norm_kernel(x_ref, w_ref, b_ref, r_ref, o_ref, *, eps: float, kind: str,
@@ -48,7 +48,7 @@ def fused_norm(
     eps: float = 1e-6,
     kind: str = "rms",
     block_rows: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     orig_shape = x.shape
     d = orig_shape[-1]
@@ -87,6 +87,7 @@ def fused_norm(
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_pad, d), x.dtype),
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=interpret_mode(interpret),
     )(x2, weight, b_arg, r_arg)
     return out[:rows].reshape(orig_shape)

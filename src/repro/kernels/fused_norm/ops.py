@@ -3,7 +3,6 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels.common import interpret_default
 from repro.kernels.fused_norm.fused_norm import fused_norm as _kernel
 
 
@@ -17,7 +16,6 @@ def fused_norm(
     kind: str = "rms",
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    interpret = interpret_default() if interpret is None else interpret
     return _kernel(
         x, weight, bias, residual, eps=eps, kind=kind, interpret=interpret
     )

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cdiv
+from repro.kernels.common import cdiv, compiler_params, interpret_mode
 
 
 def _mm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int, out_dtype):
@@ -64,7 +64,7 @@ def matmul(
     order: str = "mnk",          # "mnk" (rinse row-major) or "nmk"
     split_k: int = 1,            # >1 -> STREAM-output write-through partials
     out_dtype=None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     m, k = a.shape
     k2, n = b.shape
@@ -105,7 +105,8 @@ def matmul(
             out_specs=pl.BlockSpec((1, bm, bn), lambda s, i, j, kk: (s, i, j)),
             out_shape=jax.ShapeDtypeStruct((split_k, m, n), jnp.float32),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-            interpret=interpret,
+            compiler_params=compiler_params(),
+            interpret=interpret_mode(interpret),
         )(a, b)
         return jnp.sum(partials, axis=0).astype(out_dtype)
 
@@ -133,5 +134,6 @@ def matmul(
         out_specs=pl.BlockSpec((bm, bn), o_map),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=interpret_mode(interpret),
     )(a, b)

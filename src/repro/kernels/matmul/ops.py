@@ -5,7 +5,7 @@ import jax.numpy as jnp
 
 from repro.core import CachePolicyEngine, Policy
 from repro.core.characterize import matmul_op
-from repro.kernels.common import interpret_default, pad_dim
+from repro.kernels.common import pad_dim
 from repro.kernels.matmul.matmul import matmul as _matmul_kernel
 
 
@@ -29,7 +29,6 @@ def matmul(
     """
     m, k = a.shape
     _, n = b.shape
-    interpret = interpret_default() if interpret is None else interpret
 
     if engine is not None:
         plan = engine.plan_op(matmul_op(m, k, n, dtype=str(a.dtype)))

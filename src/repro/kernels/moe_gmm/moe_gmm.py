@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import compiler_params, interpret_mode
+
 
 def _gmm_kernel(cnt_ref, x_ref, w_ref, o_ref, acc_ref, *, k_steps: int, bm: int):
     ie = pl.program_id(0)
@@ -62,7 +64,7 @@ def grouped_matmul(
     bn: int = 256,
     bk: int = 256,
     out_dtype=None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     e, c, k = x.shape
     _, _, n = w.shape
@@ -86,5 +88,6 @@ def grouped_matmul(
         out_specs=pl.BlockSpec((1, bm, bn), lambda ie, im, jn, kk: (ie, im, jn)),
         out_shape=jax.ShapeDtypeStruct((e, c, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=interpret_mode(interpret),
     )(counts.astype(jnp.int32), x, w)

@@ -3,7 +3,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels.common import interpret_default, pad_dim
+from repro.kernels.common import pad_dim
 from repro.kernels.moe_gmm.moe_gmm import grouped_matmul as _kernel
 
 
@@ -18,7 +18,6 @@ def grouped_matmul(
     out_dtype=None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    interpret = interpret_default() if interpret is None else interpret
     e, c, k = x.shape
     n = w.shape[2]
     bm, bn, bk = min(bm, c), min(bn, n), min(bk, k)

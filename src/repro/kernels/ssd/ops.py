@@ -3,7 +3,6 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels.common import interpret_default
 from repro.kernels.ssd.ssd import ssd as _kernel
 from repro.kernels.ssd.ssd import ssd_decode_step  # noqa: F401 (re-export)
 
@@ -19,5 +18,4 @@ def ssd(
     chunk: int = 128,
     interpret: bool | None = None,
 ):
-    interpret = interpret_default() if interpret is None else interpret
     return _kernel(x, dt, A, B, C, D, chunk=chunk, interpret=interpret)

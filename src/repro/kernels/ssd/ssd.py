@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cdiv
+from repro.kernels.common import cdiv, compiler_params, interpret_mode
 
 
 def _ssd_kernel(
@@ -86,7 +86,7 @@ def ssd(
     D: jnp.ndarray | None = None,   # (h,)
     *,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (y, final_state) matching ref.ssd."""
     b, l, h, dh = x.shape
@@ -129,7 +129,8 @@ def ssd(
             jax.ShapeDtypeStruct((b, h, ds, dh), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((ds, dh), jnp.float32)],
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=interpret_mode(interpret),
     )(xdt, alog, B, C)
 
     y = y[:, :l]

@@ -1,8 +1,11 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST be the first two lines: jax locks the device count on first init.
-# 512 placeholder host devices stand in for 2 pods x 256 chips.  This is set
-# ONLY here — tests and benches see the real single CPU device.
+# ^ MUST come before jax is imported: jax locks the platform and device
+# count on first init.  This is a compile-only tool: 512 placeholder host
+# devices stand in for 2 pods x 256 chips, and pinning the CPU keeps it and
+# its --sweep children (which inherit this environment) off any attached
+# accelerator.  Set ONLY here — tests and benches see their own devices.
 
 """Multi-pod dry-run: AOT-lower + compile every (arch x shape x mesh) cell.
 
@@ -46,12 +49,7 @@ ARTIFACT_DIR = "artifacts/dryrun"
 
 
 def _cost_dict(compiled) -> dict:
-    """Portable ``compiled.cost_analysis()``: newer jax returns a list of
-    per-computation dicts, older a flat dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+    return dict(compiled.cost_analysis() or {})
 
 
 def plan_model_policies(cfg, shape, plan_cache=None) -> dict:
@@ -159,7 +157,7 @@ def lower_cell(
         batch_shardings = {
             k: NamedSharding(mesh, bspec[k]) for k in specs["batch"]
         }
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 train_step,
                 in_shardings=(state_shardings, batch_shardings),
@@ -177,7 +175,7 @@ def lower_cell(
         tok_shard = NamedSharding(mesh, P(b, None))
 
         step = model.prefill if specs["kind"] == "prefill" else model.decode_step
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step,
                 in_shardings=(pshard, cshard, tok_shard),

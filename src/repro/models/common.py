@@ -138,28 +138,17 @@ _SDPA_CHUNK_THRESHOLD = 4096 * 2048
 _SDPA_DECODE_T = 8192
 
 
-def _ambient_model_axis() -> int | None:
-    """Size of the 'model' axis of the ambient mesh (with mesh:), if any."""
-    try:
-        from jax.interpreters import pxla
-
-        mesh = pxla.thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty and "model" in mesh.axis_names:
-            return int(mesh.shape["model"])
-    except Exception:
-        pass
-    return None
-
-
 def _ambient_mesh():
-    try:
-        from jax.interpreters import pxla
+    """The mesh set by ``jax.set_mesh``, or None outside any mesh."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
-        mesh = pxla.thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except Exception:
-        pass
+
+def _ambient_model_axis() -> int | None:
+    """Size of the 'model' axis of the ambient mesh, if it has one."""
+    mesh = _ambient_mesh()
+    if mesh is not None and "model" in mesh.axis_names:
+        return int(mesh.shape["model"])
     return None
 
 
@@ -350,11 +339,17 @@ def append_kv(cache_kv: jnp.ndarray, new: jnp.ndarray, lengths: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# Paged KV layout (DESIGN.md §5.2): K/V live in an (n_pages, page_size, ...)
-# pool shared across slots; a per-slot page table (b, pages_per_slot) maps
-# logical page indices to physical page ids (-1 = unmapped).  The serve
-# engine's host-side free-list assigns pages at admission, so HBM cost
-# follows each request's actual footprint instead of slots x max_len.
+# Paged KV layout (DESIGN.md §5.2): K/V live in an (n_pages, page_size,
+# hkv * head_dim) pool shared across slots; a per-slot page table (b,
+# pages_per_slot) maps logical page indices to physical page ids (-1 =
+# unmapped).  Heads are flattened into the minor dim: on a TPU a minor dim
+# below 128 lanes (head_dim 64) pads every row to 128, and XLA then keeps
+# padded relayout copies of the whole pool inside the decode loop, which
+# does not fit one chip at minicpm-2b width; hkv * head_dim is a multiple of
+# 128 for the registered models, and one page is one (page_size, hkv *
+# head_dim) tile for the Pallas decode kernel.  The serve engine's host-side
+# free-list assigns pages at admission, so HBM cost follows each request's
+# actual footprint instead of slots x max_len.
 #
 # Nothing here knows whether two tables alias the same physical page:
 # gather/scatter are pure functions of (pool, table), so prefix sharing
@@ -382,7 +377,7 @@ def paged_kv_buffers(lead: tuple, batch: int, max_len: int, cfg,
     all-unmapped (batch, pages_per_slot) page table — the shared cache-init
     path for every paged cache family."""
     per_slot, N = paged_kv_spec(batch, max_len, cfg.kv_page_size, n_pages)
-    shape = (*lead, N, cfg.kv_page_size, cfg.n_kv_heads, cfg.head_dim_)
+    shape = (*lead, N, cfg.kv_page_size, cfg.n_kv_heads * cfg.head_dim_)
     dt = jnp.dtype(cfg.dtype)
     kv = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
     return kv, jnp.full((batch, per_slot), -1, jnp.int32)
@@ -391,7 +386,7 @@ def paged_kv_buffers(lead: tuple, batch: int, max_len: int, cfg,
 def append_kv_paged(pool: jnp.ndarray, new: jnp.ndarray, lengths: jnp.ndarray,
                     seg_lens: jnp.ndarray | None,
                     pages: jnp.ndarray) -> jnp.ndarray:
-    """Scatter a (b, s, ...) block into an (N, page_size, ...) page pool.
+    """Scatter a (b, s, hkv, d) block into an (N, page_size, hkv * d) pool.
 
     Row i of slot b lands at logical position ``lengths[b] + i``, translated
     through ``pages`` (b, P) to physical page ``pages[b, pos // page_size]``,
@@ -411,12 +406,13 @@ def append_kv_paged(pool: jnp.ndarray, new: jnp.ndarray, lengths: jnp.ndarray,
         drop = drop | ~valid
     phys = jnp.where(drop, N, phys)
     return pool.at[phys.reshape(-1), wi.reshape(-1)].set(
-        new.reshape((b * s,) + new.shape[2:]).astype(pool.dtype), mode="drop"
+        new.reshape(b * s, -1).astype(pool.dtype), mode="drop"
     )
 
 
 def gather_pages(pool: jnp.ndarray, pages: jnp.ndarray) -> jnp.ndarray:
-    """(N, page_size, ...) pool + (b, P) table -> dense (b, P*page_size, ...).
+    """(N, page_size, hkv * d) pool + (b, P) table -> dense (b, P*page_size,
+    hkv * d): the contiguous ring's rows, heads still flattened.
 
     Unmapped entries (-1) clamp to page 0: their content is garbage by
     contract and masked by the caller's ``kv_len``, exactly like the stale
@@ -430,10 +426,10 @@ def gather_pages(pool: jnp.ndarray, pages: jnp.ndarray) -> jnp.ndarray:
     bit-identical rows for the aliased positions, including within the
     admission dispatch that writes them (the scatter's output pool is the
     gather's input, so a same-wave sharer reads the owner's fresh K/V)."""
-    N, psz = pool.shape[0], pool.shape[1]
+    N, psz, hd = pool.shape
     b, P = pages.shape
-    g = jnp.take(pool, jnp.clip(pages, 0, N - 1), axis=0)     # (b, P, psz, ...)
-    return g.reshape((b, P * psz) + pool.shape[2:])
+    g = jnp.take(pool, jnp.clip(pages, 0, N - 1), axis=0)     # (b, P, psz, hd)
+    return g.reshape(b, P * psz, hd)
 
 
 def _decode_step_kernel(q, kc, vc, kv_len, cfg, pages):
@@ -466,8 +462,9 @@ def _decode_step_kernel(q, kc, vc, kv_len, cfg, pages):
                 q1, kc, vc, pages, kv_len, splits=splits
             )
         else:
-            kd = jnp.swapaxes(gather_pages(kc, pages), 1, 2)
-            vd = jnp.swapaxes(gather_pages(vc, pages), 1, 2)
+            b, hq, dh = q1.shape
+            kd, vd = (jnp.swapaxes(gather_pages(x, pages).reshape(
+                b, n_pages * psz, -1, dh), 1, 2) for x in (kc, vc))
             out = dec_ops.decode_attention(
                 q1, kd, vd, kv_len, bkv=psz, splits=splits
             )
@@ -542,8 +539,8 @@ def apply_attn(
                     q, kc, vc, kv_len, cfg, cache.get("pages")
                 )
             elif "pages" in cache:
-                k = gather_pages(kc, pages)
-                v = gather_pages(vc, pages)
+                k = gather_pages(kc, pages).reshape(b, -1, hkv, dh)
+                v = gather_pages(vc, pages).reshape(b, -1, hkv, dh)
             else:
                 k, v = kc, vc
         else:

@@ -173,9 +173,9 @@ def _stack_cached(params, x, cfg: ModelConfig, positions, vis, cache,
             # through the outer while loop, replicating per-chip temps
             # ~33x the cache size (EXPERIMENTS.md §Perf S2).  Pin it.
             # Contiguous (span, b, S, hkv, dh) shards batch over "data";
-            # the paged pool (span, N, psz, hkv, dh) has no batch axis —
+            # the paged pool (span, N, psz, hkv * dh) has no batch axis —
             # any slot may reference any page — so only heads are pinned.
-            spec = ((None, None, None, ("model",), None) if pages is not None
+            spec = ((None, None, None, ("model",)) if pages is not None
                     else (None, ("data",), ("model",), None, None))
             for key in ("k", "v"):
                 new_self[key] = cm._maybe_shard(new_self[key], spec)

@@ -248,7 +248,11 @@ class ServeEngine:
             "ServeEngine requires moe_dispatch='dense' (ragged slots would "
             "let padding contend for expert capacity under 'sorted')"
         )
-        self.policy = policy_engine or make_engine()
+        # Plan for the chip this process runs on: a TPU that no hw entry
+        # describes is an error, not a v5e by default.
+        self.policy = policy_engine or make_engine(
+            chip=hw.chip_for_device(jax.devices()[0]).name
+        )
         self.kv_residency = self.policy.kv_policy(self._kv_bytes_per_layer())
         # Decode-attention plan, memoized in the policy engine's PlanCache:
         # one lattice search + allocation per serve process, a cache hit for
@@ -1663,14 +1667,15 @@ class ServeEngine:
 
     def _pool_leaf_ids(self, leaves: list) -> list[int]:
         """Indices of the paged K/V pool leaves in the flattened cache:
-        the arrays whose trailing axes are (n_pages, page_size, heads,
+        the arrays whose trailing axes are (n_pages, page_size, heads *
         head_dim).  Slot-indexed leaves (contiguous cross K/V, recurrent
         state, the page table itself) never carry that pair of axes."""
         return [
             i for i, x in enumerate(leaves)
-            if hasattr(x, "ndim") and x.ndim >= 4
-            and x.shape[-4] == self.n_pages
-            and x.shape[-3] == self.page_size
+            if hasattr(x, "ndim") and x.ndim >= 3
+            and x.shape[-3] == self.n_pages
+            and x.shape[-2] == self.page_size
+            and x.shape[-1] == self.cfg.n_kv_heads * self.cfg.head_dim_
             and jnp.issubdtype(x.dtype, jnp.floating)
         ]
 
@@ -1692,7 +1697,7 @@ class ServeEngine:
             c = 0
             for pool in pools:
                 c = zlib.crc32(
-                    np.ascontiguousarray(pool[..., p, :, :, :]).tobytes(), c
+                    np.ascontiguousarray(pool[..., p, :, :]).tobytes(), c
                 )
             out[p] = c
         return out
@@ -1716,7 +1721,7 @@ class ServeEngine:
         expose it).  Device-side, exactly like real HBM corruption."""
         leaves, treedef = jax.tree_util.tree_flatten(self.cache)
         i = self._pool_leaf_ids(leaves)[0]
-        leaves[i] = leaves[i].at[..., page, 0, 0, 0].add(1)
+        leaves[i] = leaves[i].at[..., page, 0, 0].add(1)
         self.cache = jax.tree_util.tree_unflatten(treedef, leaves)
 
     def _integrity_sweep(self) -> list[int]:

@@ -12,7 +12,8 @@ _SNIPPET_HEADER = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_mesh
 """
 
 
@@ -30,7 +31,7 @@ def _run(snippet: str, timeout=420):
 def test_sp_decode_matches_reference():
     _run("""
     from repro.distributed.sp_decode import sp_decode_attention, reference
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     b, hq, hkv, S, d = 2, 8, 2, 512, 64
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q = jax.random.normal(ks[0], (b, hq, d), jnp.float32)
@@ -47,10 +48,9 @@ def test_sp_decode_matches_reference():
 
 def test_bucketed_and_compressed_all_reduce():
     _run("""
-    from jax.experimental.shard_map import shard_map
     from repro.distributed.collectives import (bucketed_all_reduce,
                                                compressed_all_reduce)
-    mesh = jax.make_mesh((8,), ("d",))
+    mesh = make_mesh((8,), ("d",))
     gs = [jax.random.normal(jax.random.PRNGKey(i), (8, 13 + i), jnp.float32)
           for i in range(5)]
 
@@ -58,7 +58,7 @@ def test_bucketed_and_compressed_all_reduce():
         outs = bucketed_all_reduce(list(gs), "d", bucket_bytes=256)
         return tuple(outs)
 
-    outs = shard_map(f, mesh=mesh,
+    outs = jax.shard_map(f, mesh=mesh,
                      in_specs=tuple(P("d") for _ in gs),
                      out_specs=tuple(P("d") for _ in gs))(*gs)
     for g, o in zip(gs, outs):
@@ -74,7 +74,7 @@ def test_bucketed_and_compressed_all_reduce():
     def c(g, e):
         return compressed_all_reduce(g, e, "d")
 
-    red, err = shard_map(c, mesh=mesh, in_specs=(P("d"), P("d")),
+    red, err = jax.shard_map(c, mesh=mesh, in_specs=(P("d"), P("d")),
                          out_specs=(P("d"), P("d")))(g, err0)
     want = jnp.mean(g, axis=0)
     got = np.asarray(red[0])
@@ -105,14 +105,14 @@ def test_sharded_train_step_matches_single_device():
     # single device reference
     ref_state, ref_metrics = jax.jit(train_step)(state, batch)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     pshard = sh.params_shardings(state["params"], cfg, mesh)
     oshard = opt.opt_shardings(pshard, state["params"], mesh, zero1=True)
     sshard = {"params": pshard, "opt": oshard}
     bspec = sh.batch_spec(cfg, mesh, 8)
     bshard = {k: NamedSharding(mesh, bspec[k]) for k in batch}
     state2 = init_train_state(model, jax.random.PRNGKey(0))
-    with mesh:
+    with jax.set_mesh(mesh):
         state2 = jax.device_put(state2, sshard)
         batch2 = jax.device_put(batch, bshard)
         new_state, metrics = jax.jit(
@@ -141,9 +141,9 @@ def test_moe_ep_sharded_forward_matches():
     toks = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg.vocab)
     want, _ = model.forward(params, toks)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     pshard = sh.params_shardings(params, cfg, mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         params2 = jax.device_put(params, pshard)
         toks2 = jax.device_put(toks, NamedSharding(mesh, P("data", None)))
         got, _ = jax.jit(model.forward)(params2, toks2)

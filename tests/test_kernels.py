@@ -103,12 +103,13 @@ class TestDecodeAttention:
         )
 
 
-def _gather(pool, pages):
-    """gather_pages' clamp-to-page-0 contract, inlined for independence."""
-    N, psz = pool.shape[0], pool.shape[1]
+def _gather(pool, pages, d):
+    """gather_pages' clamp-to-page-0 contract, inlined for independence:
+    (N, psz, hkv * d) pool -> the dense kernel's (b, hkv, P*psz, d)."""
+    N, psz, hd = pool.shape
     b, P = pages.shape
     g = jnp.take(pool, jnp.clip(pages, 0, N - 1), axis=0)
-    return g.reshape((b, P * psz) + pool.shape[2:])
+    return jnp.swapaxes(g.reshape(b, P * psz, hd // d, d), 1, 2)
 
 
 def _paged_case(key, b, hq, hkv, N, psz, P, d, dtype, unmapped_tail=True):
@@ -118,8 +119,8 @@ def _paged_case(key, b, hq, hkv, N, psz, P, d, dtype, unmapped_tail=True):
     exact page-boundary hits, with optional unmapped -1 tails."""
     ks = jax.random.split(key, 6)
     q = _rand(ks[0], (b, hq, d), dtype)
-    k_pool = _rand(ks[1], (N, psz, hkv, d), dtype)
-    v_pool = _rand(ks[2], (N, psz, hkv, d), dtype)
+    k_pool = _rand(ks[1], (N, psz, hkv * d), dtype)
+    v_pool = _rand(ks[2], (N, psz, hkv * d), dtype)
     pages = jax.random.randint(ks[3], (b, P), 0, N).astype(jnp.int32)
     mapped = jax.random.randint(ks[4], (b,), 1, P + 1)
     if unmapped_tail:
@@ -151,8 +152,8 @@ class TestPagedDecodeAttention:
         )
         got = ops.paged_decode_attention(q, kp, vp, pages, lengths,
                                          splits=splits)
-        kd = jnp.swapaxes(_gather(kp, pages), 1, 2)
-        vd = jnp.swapaxes(_gather(vp, pages), 1, 2)
+        kd = _gather(kp, pages, d)
+        vd = _gather(vp, pages, d)
         want = ops.decode_attention(q, kd, vd, lengths, bkv=psz,
                                     splits=splits)
         # Bitwise: the paged index-map indirection must change nothing.
@@ -172,8 +173,8 @@ class TestPagedDecodeAttention:
         ks = jax.random.split(jax.random.PRNGKey(8), 3)
         q1 = _rand(ks[0], (1, 4, d), jnp.float32)
         q = jnp.concatenate([q1, q1], axis=0)
-        kp = _rand(ks[1], (6, psz, 2, d), jnp.float32)
-        vp = _rand(ks[2], (6, psz, 2, d), jnp.float32)
+        kp = _rand(ks[1], (6, psz, 2 * d), jnp.float32)
+        vp = _rand(ks[2], (6, psz, 2 * d), jnp.float32)
         pages = jnp.asarray([[2, 5, 2], [2, 5, 2]], jnp.int32)
         lengths = jnp.asarray([20, 20], jnp.int32)
         out = ops.paged_decode_attention(q, kp, vp, pages, lengths, splits=2)
@@ -196,8 +197,8 @@ class TestPagedDecodeAttention:
         reachable = jnp.zeros((N,), bool).at[jnp.asarray([3, 1, 6, 0])].set(
             True
         )  # page 0 is the -1 clamp target: read (masked), so keep it clean
-        poison = jnp.where(reachable[:, None, None, None], kp, 1e9)
-        vpois = jnp.where(reachable[:, None, None, None], vp, -1e9)
+        poison = jnp.where(reachable[:, None, None], kp, 1e9)
+        vpois = jnp.where(reachable[:, None, None], vp, -1e9)
         dirty = ops.paged_decode_attention(q, poison, vpois, pages, lengths)
         np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
 
@@ -276,13 +277,49 @@ class TestDecodeAttentionPlanning:
         from repro.kernels.decode_attention.decode_attention import (
             decode_attention, paged_decode_attention,
         )
+        from repro.kernels.flash_attention.flash_attention import (
+            flash_attention,
+        )
+        from repro.kernels.fused_norm.fused_norm import fused_norm
+        from repro.kernels.matmul.matmul import matmul
+        from repro.kernels.moe_gmm.moe_gmm import grouped_matmul
+        from repro.kernels.ssd.ssd import ssd
 
-        for fn in (decode_attention, paged_decode_attention):
+        for fn in (decode_attention, paged_decode_attention, flash_attention,
+                   fused_norm, matmul, grouped_matmul, ssd):
             sig = inspect.signature(fn)
             assert sig.parameters["interpret"].default is None, (
-                "inner kernels must defer to interpret_default(), not "
+                "inner kernels must defer to interpret_mode(), not "
                 "hard-code interpret=True (silently interpreted on TPU)"
             )
+
+
+class TestChipSelection:
+    """On a TPU kernels compile (never interpret), and the chip model comes
+    from the device kind, with no default for a TPU it does not describe."""
+
+    def test_interpret_mode_follows_backend(self, monkeypatch):
+        from repro.kernels import common
+
+        assert common.interpret_mode(None) is True       # this CPU backend
+        assert common.interpret_mode(False) is False
+        monkeypatch.setattr(common.jax, "default_backend", lambda: "tpu")
+        assert common.interpret_mode(None) is False
+        with pytest.raises(ValueError, match="not interpreted"):
+            common.interpret_mode(True)
+
+    def test_chip_for_device(self):
+        import types
+
+        from repro import hw
+
+        def dev(platform, kind):
+            return types.SimpleNamespace(platform=platform, device_kind=kind)
+
+        assert hw.chip_for_device(dev("tpu", "TPU v5 lite")) is hw.V5E
+        assert hw.chip_for_device(dev("cpu", "cpu")) is hw.V5E
+        with pytest.raises(ValueError, match="TPU v9"):
+            hw.chip_for_device(dev("tpu", "TPU v9"))
 
 
 class TestSSD:
