@@ -504,55 +504,60 @@ def apply_attn(
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
 
-    new_cache = None
-    kv_len = None
-    kernel_out = None
-    q_offset: Any = 0
-    is_cross_cached = cache is not None and "lengths" not in cache
-    if cache is not None:
-        if kv_src is None and not is_cross_cached:
-            # Self-attention decode/prefill-append: scatter at per-slot
-            # cursors.  cache["lengths"] is the (b,) int32 ragged cursor
-            # vector; slots free and re-admit independently.  Positions at
-            # or beyond each slot's valid length hold stale bytes but are
-            # masked out via kv_len below and overwritten as the cursor
-            # advances.
-            lengths = cache["lengths"]
-            if "pages" in cache:
-                # Paged pool: scatter through the page table.  The XLA
-                # path then gathers a dense per-slot view for the masked
-                # online-softmax; the Pallas decode-step path below reads
-                # the pool in place instead — no gather copy.
-                pages = cache["pages"]
-                kc = append_kv_paged(cache["k"], k, lengths, seg_lens, pages)
-                vc = append_kv_paged(cache["v"], v, lengths, seg_lens, pages)
-            else:
-                kc = append_kv(cache["k"], k, lengths, seg_lens)
-                vc = append_kv(cache["v"], v, lengths, seg_lens)
-            kv_len = lengths + (
-                jnp.int32(s) if seg_lens is None else seg_lens
-            )
-            new_cache = {"k": kc, "v": vc}
-            q_offset = lengths
-            if cfg.decode_kernel != "xla" and s == 1 and causal:
-                kernel_out = _decode_step_kernel(
-                    q, kc, vc, kv_len, cfg, cache.get("pages")
+    # KV append, page gather or paged kernel, and the attention math:
+    # what a change of decode-attention implementation replaces.
+    with jax.named_scope("attn"):
+        new_cache = None
+        kv_len = None
+        kernel_out = None
+        q_offset: Any = 0
+        is_cross_cached = cache is not None and "lengths" not in cache
+        if cache is not None:
+            if kv_src is None and not is_cross_cached:
+                # Self-attention decode/prefill-append: scatter at per-slot
+                # cursors.  cache["lengths"] is the (b,) int32 ragged
+                # cursor vector; slots free and re-admit independently.
+                # Positions at or beyond each slot's valid length hold
+                # stale bytes but are masked out via kv_len below and
+                # overwritten as the cursor advances.
+                lengths = cache["lengths"]
+                if "pages" in cache:
+                    # Paged pool: scatter through the page table.  The XLA
+                    # path then gathers a dense per-slot view for the masked
+                    # online-softmax; the Pallas decode-step path below reads
+                    # the pool in place instead — no gather copy.
+                    pages = cache["pages"]
+                    kc = append_kv_paged(cache["k"], k, lengths,
+                                         seg_lens, pages)
+                    vc = append_kv_paged(cache["v"], v, lengths,
+                                         seg_lens, pages)
+                else:
+                    kc = append_kv(cache["k"], k, lengths, seg_lens)
+                    vc = append_kv(cache["v"], v, lengths, seg_lens)
+                kv_len = lengths + (
+                    jnp.int32(s) if seg_lens is None else seg_lens
                 )
-            elif "pages" in cache:
-                k = gather_pages(kc, pages).reshape(b, -1, hkv, dh)
-                v = gather_pages(vc, pages).reshape(b, -1, hkv, dh)
+                new_cache = {"k": kc, "v": vc}
+                q_offset = lengths
+                if cfg.decode_kernel != "xla" and s == 1 and causal:
+                    kernel_out = _decode_step_kernel(
+                        q, kc, vc, kv_len, cfg, cache.get("pages")
+                    )
+                elif "pages" in cache:
+                    k = gather_pages(kc, pages).reshape(b, -1, hkv, dh)
+                    v = gather_pages(vc, pages).reshape(b, -1, hkv, dh)
+                else:
+                    k, v = kc, vc
             else:
-                k, v = kc, vc
+                # Cross-attention: cache holds precomputed source K/V.
+                k, v = cache["k"], cache["v"]
+                new_cache = cache
+        is_cross = kv_src is not None or is_cross_cached
+        if kernel_out is not None:
+            out = kernel_out
         else:
-            # Cross-attention: cache holds precomputed source K/V.
-            k, v = cache["k"], cache["v"]
-            new_cache = cache
-    is_cross = kv_src is not None or is_cross_cached
-    if kernel_out is not None:
-        out = kernel_out
-    else:
-        out = _sdpa(q, k, v, causal=causal and not is_cross,
-                    q_offset=q_offset, kv_len=kv_len)
+            out = _sdpa(q, k, v, causal=causal and not is_cross,
+                        q_offset=q_offset, kv_len=kv_len)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return y, new_cache
 
@@ -578,11 +583,12 @@ def mlp_init(key, cfg: ModelConfig) -> Params:
 
 
 def apply_mlp(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
-    if cfg.act == "swiglu":
-        h = jax.nn.silu(x @ p["wg"]) * (x @ p["wu"])
-    else:
-        h = jax.nn.gelu(x @ p["wu"])
-    return h @ p["wd"]
+    with jax.named_scope("mlp"):
+        if cfg.act == "swiglu":
+            h = jax.nn.silu(x @ p["wg"]) * (x @ p["wu"])
+        else:
+            h = jax.nn.gelu(x @ p["wu"])
+        return h @ p["wd"]
 
 
 # ---------------------------------------------------------------------------
@@ -695,9 +701,10 @@ def embed(p: Params, tokens: jnp.ndarray) -> jnp.ndarray:
 
 
 def unembed(p: Params, h: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
-    if cfg.tie_embeddings:
-        return jnp.einsum("bsd,vd->bsv", h, p["tok"])
-    return jnp.einsum("bsd,dv->bsv", h, p["unembed"])
+    with jax.named_scope("unembed"):
+        if cfg.tie_embeddings:
+            return jnp.einsum("bsd,vd->bsv", h, p["tok"])
+        return jnp.einsum("bsd,dv->bsv", h, p["unembed"])
 
 
 # Vocab-chunked logsumexp above this size: never materializes the fp32

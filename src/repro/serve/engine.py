@@ -106,6 +106,7 @@ from repro.serve.alloc import PageAllocator  # noqa: F401  (re-export: the
 from repro.serve.chaos import ChaosAllocator, ChaosCrash
 from repro.serve.draft import ngram_propose
 from repro.serve.prefix import PrefixIndex
+from repro.serve.spans import span
 from repro.serve.snapshot import SnapshotError  # noqa: F401  (re-export:
 # engine callers catch restore failures without importing serve.snapshot)
 from repro.serve.sampling import (  # noqa: F401  (greedy_sample re-export)
@@ -405,6 +406,7 @@ class ServeEngine:
             "admitted_fresh": 0,      # first-time admissions (no tokens yet)
             "readmitted": 0,          # preemption-restore re-admissions
             "prefill_work_tokens": 0,  # suffix tokens actually prefilled
+            "prefill_rows_computed": 0,  # slots x pad rows the waves ran
             "spec_tokens": 0,         # tokens emitted by draft/verify rounds
             "prefix_hits": 0,         # admissions that attached shared pages
             "prefix_hits_fresh": 0,   # ... the fresh-admission subset
@@ -665,9 +667,10 @@ class ServeEngine:
         """Sampler dispatch: per-slot keys folded from (request seed, token
         index) — a pure function of the request, so streams are independent
         of slot assignment and batch composition."""
-        keys = (sample_keys(seeds, tok_idx)
-                if self.sampler.needs_keys else None)
-        return self.sampler(logits, keys).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            keys = (sample_keys(seeds, tok_idx)
+                    if self.sampler.needs_keys else None)
+            return self.sampler(logits, keys).astype(jnp.int32)
 
     def _hist_append(self, hist, positions, tokens):
         """Scatter ``tokens`` into per-slot history at ``positions``;
@@ -1178,13 +1181,14 @@ class ServeEngine:
         re-enters at the queue FRONT: residents are always older than
         anything queued (FIFO admission), so appendleft preserves global
         arrival order."""
-        self._release_slot(victim)
-        victim.status = "preempted"
-        victim.preempted_n += 1
-        self.queue.appendleft(victim)
-        self.stats["preempted"] += 1
-        if forced:
-            self.stats["preempted_forced"] += 1
+        with span("serve.preempt", id=victim.id):
+            self._release_slot(victim)
+            victim.status = "preempted"
+            victim.preempted_n += 1
+            self.queue.appendleft(victim)
+            self.stats["preempted"] += 1
+            if forced:
+                self.stats["preempted_forced"] += 1
 
     def _chaos_forced_preempt(self) -> None:
         """Chaos knob: with seeded probability cfg.chaos_preempt_p, force-
@@ -1301,115 +1305,57 @@ class ServeEngine:
             self._preempt(victim)
 
     def _admit_wave(self) -> None:
-        if self._chaos_rng is not None:
-            self._chaos_forced_preempt()
-        if self.adaptive is not None:
-            self.adaptive.begin_wave()
-        # Wave entries carry the request's EFFECTIVE prompt (prompt +
-        # previously emitted tokens for a preempted request being
-        # restored, DESIGN.md §5.5) — everything downstream (page demand,
-        # prefix chunks, prefill buffers, history) treats it as the
-        # prompt.
-        wave: list[tuple[int, Request, np.ndarray]] = []
-        wave_slots: set[int] = set()
-        now = time.perf_counter()
-        while self.queue:
-            slot = next(
-                (i for i, q in enumerate(self.slot_req) if q is None), None
-            )
-            if slot is None:
-                break
-            # Pop the head BEFORE any preemption retry: victims re-enter
-            # at the queue front (appendleft), which would displace a head
-            # still sitting at queue[0].
-            head = self.queue.popleft()
-            eff = self._effective_prompt(head)
-            if self.paged:
-                # Admission gates on free pages (FIFO head-of-line: a
-                # request that doesn't fit waits — or preempts — rather
-                # than being overtaken).  With prefix sharing the head
-                # only needs pages for its UNSHARED suffix; the shared
-                # prefix rides resident pages via a refcount bump.
-                table, chunks, shared_len = self._acquire_pages(
-                    head, eff, wave_slots
+        with span("serve.admit", wave=self.stats["admission_waves"] + 1):
+            if self._chaos_rng is not None:
+                self._chaos_forced_preempt()
+            if self.adaptive is not None:
+                self.adaptive.begin_wave()
+            # Wave entries carry the request's EFFECTIVE prompt (prompt +
+            # previously emitted tokens for a preempted request being
+            # restored, DESIGN.md §5.5) — everything downstream (page
+            # demand, prefix chunks, prefill buffers, history) treats it
+            # as the prompt.
+            wave: list[tuple[int, Request, np.ndarray]] = []
+            wave_slots: set[int] = set()
+            now = time.perf_counter()
+            while self.queue:
+                slot = next(
+                    (i for i, q in enumerate(self.slot_req) if q is None), None
                 )
-                if table is None:
+                if slot is None:
+                    break
+                # Pop the head BEFORE any preemption retry: victims re-enter
+                # at the queue front (appendleft), which would displace a head
+                # still sitting at queue[0].
+                head = self.queue.popleft()
+                with span("serve.admit.request", id=head.id) as sp:
+                    eff = self._admit_head(head, slot, wave_slots, now)
+                    if eff is not None:
+                        sp.set_metadata(
+                            prefix=head.prefix_tokens,
+                            suffix=len(eff) - head.prefix_tokens)
+                if eff is None:
                     self.queue.appendleft(head)
                     break
-                head.prefix_tokens = shared_len
-                self._slot_pages[slot] = table
-                self.page_table[slot] = -1
-                self.page_table[slot, :len(table)] = table
-                if self.prefix is not None:
-                    # Index this prompt's own full pages so later requests
-                    # can attach; already-resident chunks keep their
-                    # existing (shared) nodes.
-                    self.prefix.register(eff, table[:len(chunks)],
-                                         chunks=chunks)
-                    if self.adaptive is not None:
-                        # Classify by prompt content (first full page) and
-                        # remember which class DECIDES this request's
-                        # retention at release time.  A readmission keeps
-                        # its original deciding class — its effective
-                        # prompt grew, so re-hashing would re-classify.
-                        key = self.adaptive.class_key(chunks)
-                        head._adaptive_key = key
-                        if head.generated:
-                            head._adaptive_class = getattr(
-                                head, "_adaptive_class", key
-                            )
-                        else:
-                            head._adaptive_class = self.adaptive.note_arrival(
-                                key, len(eff),
-                                ((len(eff) - 1) // self.page_size)
-                                * self.page_size,
-                            )
-                        self.adaptive.touch(table)
-                    if shared_len:
-                        self.stats["prefix_hits"] += 1
-                        if not head.generated:
-                            self.stats["prefix_hits_fresh"] += 1
-                        self.stats["prefix_pages_shared"] += (
-                            shared_len // self.page_size
-                        )
-                        self.stats["prefix_tokens_shared"] += shared_len
-            else:
-                head.prefix_tokens = 0    # contiguous: always a full prefill
-            # The chunk memo exists only to amortize head-of-line retries;
-            # drop it at admission so engine-private (and page-size-
-            # dependent) state never outlives the queue.
-            head.__dict__.pop("_prefix_chunks", None)
-            head.admit_t = now
-            if head.submit_t is not None and head.queue_wait_s is None:
-                head.queue_wait_s = now - head.submit_t
-            head.status = "resident"
-            head.slot = slot
-            head.admit_seq = self._admit_seq
-            self._admit_seq += 1
-            self.slot_req[slot] = head
-            if head.generated:
-                # Preemption restore: its prefill replays work already
-                # done once, so it must NOT dilute fresh-admission rates
-                # (the serve_stats prefix_hit_rate bug this split fixes).
-                self.stats["readmitted"] += 1
-                self.stats["recompute_tokens"] += len(head.generated)
-            else:
-                self.stats["admitted_fresh"] += 1
-            wave.append((slot, head, eff))
-            wave_slots.add(slot)
-        # Park slots vacated mid-stream (preempt/cancel/expire) that this
-        # wave did not refill: their device budget must hit zero before
-        # the next chunk, or they would keep decoding into pages now
-        # owned by others.  (Wave slots are re-armed by the prefill's
-        # admitted mask, so they need no zeroing.)
-        stale = sorted(self._dirty_slots - wave_slots)
-        self._dirty_slots.clear()
-        if stale:
-            self.remaining = self.remaining.at[jnp.asarray(stale)].set(0)
+                wave.append((slot, head, eff))
+                wave_slots.add(slot)
+            # Park slots vacated mid-stream (preempt/cancel/expire) that this
+            # wave did not refill: their device budget must hit zero before
+            # the next chunk, or they would keep decoding into pages now
+            # owned by others.  (Wave slots are re-armed by the prefill's
+            # admitted mask, so they need no zeroing.)
+            stale = sorted(self._dirty_slots - wave_slots)
+            self._dirty_slots.clear()
+            if stale:
+                self.remaining = self.remaining.at[jnp.asarray(stale)].set(0)
         if not wave:
             if self._chaos or self._strict:
                 self.check_invariants()
             return
+        # Admission consults the policy engine: KV residency for the current
+        # occupancy and the (PlanCache-memoized) decode-attention plan.
+        with span("serve.plan"):
+            self.decode_plan = self._plan_decode()
         # Attached slots prefill only their unshared suffix (prefix_tokens
         # is 0 without sharing), so the pad bucket — and the prefill's
         # compute — shrinks to the widest *suffix* in the wave.  The
@@ -1420,6 +1366,123 @@ class ServeEngine:
             max(len(eff) - r.prefix_tokens for _, r, eff in wave),
             self.max_len,
         )
+        with span("serve.prefill", rows=self.slots * pad):
+            nxt = self._prefill_wave(wave, pad)
+        self.stats["prefill_rows_computed"] += self.slots * pad
+        with span("serve.prefill.sync"):
+            first = np.asarray(nxt)            # host sync: 1 per wave
+        self.stats["host_syncs"] += 1
+        self.stats["admission_waves"] += 1
+        if (self.adaptive is not None and self.adaptive.pinned is None
+                and self.stats["admission_waves"]
+                % self.adaptive.replan_every == 0):
+            # Re-plan boundary: feed the counters through the serve-policy
+            # lattice (core/sweep.py) and install per-class combos.
+            # Placement-only — outputs are bit-identical either way.
+            with span("serve.replan"):
+                self.adaptive.replan(self.stats)
+            self.stats["replans"] += 1
+        if self.paged:
+            self.stats["peak_pages_held"] = max(
+                self.stats["peak_pages_held"],
+                self.n_pages - self.allocator.free_count(),
+            )
+        with span("serve.emit"):
+            now = time.perf_counter()
+            for _, r, _ in wave:
+                r.generated.append(int(first[r.slot]))
+                self.stats["prefill_tokens"] += 1
+                if r.ttft_s is None and r.admit_t is not None:
+                    # True TTFT: admission -> first token (prefill compute);
+                    # queueing is reported separately as queue_wait_s.
+                    r.ttft_s = now - r.admit_t
+                if len(r.generated) >= r.max_new_tokens:
+                    self._finish(r)
+        if self._chaos or self._strict:
+            self.check_invariants()
+
+    def _admit_head(self, head: Request, slot: int, wave_slots: set[int],
+                    now: float) -> np.ndarray | None:
+        """Admit the popped queue head into ``slot``: pages, prefix attach
+        and registration, lifecycle state.  Returns its effective prompt,
+        or None where the pool gates it (then nothing was taken)."""
+        eff = self._effective_prompt(head)
+        if self.paged:
+            # Admission gates on free pages (FIFO head-of-line: a
+            # request that doesn't fit waits — or preempts — rather
+            # than being overtaken).  With prefix sharing the head
+            # only needs pages for its UNSHARED suffix; the shared
+            # prefix rides resident pages via a refcount bump.
+            table, chunks, shared_len = self._acquire_pages(
+                head, eff, wave_slots
+            )
+            if table is None:
+                return None
+            head.prefix_tokens = shared_len
+            self._slot_pages[slot] = table
+            self.page_table[slot] = -1
+            self.page_table[slot, :len(table)] = table
+            if self.prefix is not None:
+                # Index this prompt's own full pages so later requests
+                # can attach; already-resident chunks keep their
+                # existing (shared) nodes.
+                self.prefix.register(eff, table[:len(chunks)],
+                                     chunks=chunks)
+                if self.adaptive is not None:
+                    # Classify by prompt content (first full page) and
+                    # remember which class DECIDES this request's
+                    # retention at release time.  A readmission keeps
+                    # its original deciding class — its effective
+                    # prompt grew, so re-hashing would re-classify.
+                    key = self.adaptive.class_key(chunks)
+                    head._adaptive_key = key
+                    if head.generated:
+                        head._adaptive_class = getattr(
+                            head, "_adaptive_class", key
+                        )
+                    else:
+                        head._adaptive_class = self.adaptive.note_arrival(
+                            key, len(eff),
+                            ((len(eff) - 1) // self.page_size)
+                            * self.page_size,
+                        )
+                    self.adaptive.touch(table)
+                if shared_len:
+                    self.stats["prefix_hits"] += 1
+                    if not head.generated:
+                        self.stats["prefix_hits_fresh"] += 1
+                    self.stats["prefix_pages_shared"] += (
+                        shared_len // self.page_size
+                    )
+                    self.stats["prefix_tokens_shared"] += shared_len
+        else:
+            head.prefix_tokens = 0    # contiguous: always a full prefill
+        # The chunk memo exists only to amortize head-of-line retries;
+        # drop it at admission so engine-private (and page-size-
+        # dependent) state never outlives the queue.
+        head.__dict__.pop("_prefix_chunks", None)
+        head.admit_t = now
+        if head.submit_t is not None and head.queue_wait_s is None:
+            head.queue_wait_s = now - head.submit_t
+        head.status = "resident"
+        head.slot = slot
+        head.admit_seq = self._admit_seq
+        self._admit_seq += 1
+        self.slot_req[slot] = head
+        if head.generated:
+            # Preemption restore: its prefill replays work already
+            # done once, so it must NOT dilute fresh-admission rates
+            # (the serve_stats prefix_hit_rate bug this split fixes).
+            self.stats["readmitted"] += 1
+            self.stats["recompute_tokens"] += len(head.generated)
+        else:
+            self.stats["admitted_fresh"] += 1
+        return eff
+
+    def _prefill_wave(self, wave: list[tuple[int, Request, np.ndarray]],
+                      pad: int) -> jax.Array:
+        """Build the wave's prefill arguments and dispatch the prefill;
+        returns the device array of each slot's first token."""
         # The full-prompt history buffer only differs from the prefill
         # buffer when some wave member attached a prefix; otherwise the
         # suffix IS the prompt and one buffer serves both arguments.
@@ -1465,9 +1528,6 @@ class ServeEngine:
             # The table is tiny; replacing the leaf keeps the jitted prefill
             # signature layout-independent (donation still applies).
             self.cache = {**self.cache, "pages": jnp.asarray(self.page_table)}
-        # Admission consults the policy engine: KV residency for the current
-        # occupancy and the (PlanCache-memoized) decode-attention plan.
-        self.decode_plan = self._plan_decode()
         toks_d = jnp.asarray(toks)
         htoks_d = jnp.asarray(htoks) if attached else toks_d
         (self.cache, self.cur_tok, self.remaining, self.tok_idx, self.hist,
@@ -1478,85 +1538,64 @@ class ServeEngine:
             self.tok_idx, self.hist, self.hist_len, jnp.asarray(new_seeds),
             self.seeds,
         )
-        first = np.asarray(nxt)                # host sync: 1 per wave
-        self.stats["host_syncs"] += 1
-        self.stats["admission_waves"] += 1
-        if (self.adaptive is not None and self.adaptive.pinned is None
-                and self.stats["admission_waves"]
-                % self.adaptive.replan_every == 0):
-            # Re-plan boundary: feed the counters through the serve-policy
-            # lattice (core/sweep.py) and install per-class combos.
-            # Placement-only — outputs are bit-identical either way.
-            self.adaptive.replan(self.stats)
-            self.stats["replans"] += 1
-        if self.paged:
-            self.stats["peak_pages_held"] = max(
-                self.stats["peak_pages_held"],
-                self.n_pages - self.allocator.free_count(),
-            )
-        now = time.perf_counter()
-        for _, r, _ in wave:
-            r.generated.append(int(first[r.slot]))
-            self.stats["prefill_tokens"] += 1
-            if r.ttft_s is None and r.admit_t is not None:
-                # True TTFT: admission -> first token (prefill compute);
-                # queueing is reported separately as queue_wait_s.
-                r.ttft_s = now - r.admit_t
-            if len(r.generated) >= r.max_new_tokens:
-                self._finish(r)
-        if self._chaos or self._strict:
-            self.check_invariants()
+        return nxt
 
     def _run_chunk(self) -> None:
-        (self.cache, self.cur_tok, self.remaining, self.tok_idx, self.hist,
-         self.hist_len, toks, actives) = self._decode_chunk(
-            self.params, self.cache, self.cur_tok, self.remaining,
-            self.tok_idx, self.hist, self.hist_len, self.seeds,
-        )
-        t_np, a_np = jax.device_get((toks, actives))   # host sync: 1 per chunk
+        with span("serve.decode"):
+            (self.cache, self.cur_tok, self.remaining, self.tok_idx,
+             self.hist, self.hist_len, toks, actives) = self._decode_chunk(
+                self.params, self.cache, self.cur_tok, self.remaining,
+                self.tok_idx, self.hist, self.hist_len, self.seeds,
+            )
+        with span("serve.decode.sync"):
+            t_np, a_np = jax.device_get((toks, actives))  # 1 sync/chunk
         self.stats["host_syncs"] += 1
         self.stats["decode_syncs"] += 1
         self.stats["chunks"] += 1
-        for slot, r in self._live():
-            emitted = a_np[:, slot]
-            for i in np.nonzero(emitted)[0]:
-                r.generated.append(int(t_np[i, slot]))
-            self.stats["decode_tokens"] += int(emitted.sum())
-            if len(r.generated) >= r.max_new_tokens:
-                self._finish(r)
+        with span("serve.emit"):
+            for slot, r in self._live():
+                emitted = a_np[:, slot]
+                for i in np.nonzero(emitted)[0]:
+                    r.generated.append(int(t_np[i, slot]))
+                self.stats["decode_tokens"] += int(emitted.sum())
+                if len(r.generated) >= r.max_new_tokens:
+                    self._finish(r)
 
     def _run_spec_chunk(self) -> None:
-        (self.cache, self.cur_tok, self.remaining, self.tok_idx, self.hist,
-         self.hist_len, toks, emits, accepts, proposed,
-         actives) = self._decode_chunk(
-            self.params, self.cache, self.cur_tok, self.remaining,
-            self.tok_idx, self.hist, self.hist_len, self.seeds,
-        )
+        with span("serve.decode"):
+            (self.cache, self.cur_tok, self.remaining, self.tok_idx,
+             self.hist, self.hist_len, toks, emits, accepts, proposed,
+             actives) = self._decode_chunk(
+                self.params, self.cache, self.cur_tok, self.remaining,
+                self.tok_idx, self.hist, self.hist_len, self.seeds,
+            )
         # toks/emits: (rounds, b, k+1); accepts/proposed/actives: (rounds, b).
-        t_np, e_np, acc_np, prop_np, act_np = jax.device_get(
-            (toks, emits, accepts, proposed, actives)
-        )                                              # host sync: 1 per chunk
+        with span("serve.decode.sync"):
+            t_np, e_np, acc_np, prop_np, act_np = jax.device_get(
+                (toks, emits, accepts, proposed, actives)
+            )                                          # host sync: 1 per chunk
         self.stats["host_syncs"] += 1
         self.stats["decode_syncs"] += 1
         self.stats["chunks"] += 1
-        for slot, r in self._live():
-            for j in range(t_np.shape[0]):
-                if not act_np[j, slot]:
-                    continue
-                row = e_np[j, slot]
-                for t in t_np[j, slot][row]:
-                    r.generated.append(int(t))
-                self.stats["decode_tokens"] += int(row.sum())
-                # Spec-round-emitted tokens in their OWN counter: the old
-                # spec_tokens_per_round divided ALL decode tokens (non-
-                # spec chunks included) by spec_rounds, inflating the
-                # ratio whenever plain decode ran in the same session.
-                self.stats["spec_tokens"] += int(row.sum())
-                self.stats["spec_rounds"] += 1
-                self.stats["draft_proposed"] += int(prop_np[j, slot])
-                self.stats["draft_accepted"] += int(acc_np[j, slot])
-            if len(r.generated) >= r.max_new_tokens:
-                self._finish(r)
+        with span("serve.emit"):
+            for slot, r in self._live():
+                for j in range(t_np.shape[0]):
+                    if not act_np[j, slot]:
+                        continue
+                    row = e_np[j, slot]
+                    for t in t_np[j, slot][row]:
+                        r.generated.append(int(t))
+                    self.stats["decode_tokens"] += int(row.sum())
+                    # Spec-round-emitted tokens in their OWN counter: the old
+                    # spec_tokens_per_round divided ALL decode tokens (non-
+                    # spec chunks included) by spec_rounds, inflating the
+                    # ratio whenever plain decode ran in the same session.
+                    self.stats["spec_tokens"] += int(row.sum())
+                    self.stats["spec_rounds"] += 1
+                    self.stats["draft_proposed"] += int(prop_np[j, slot])
+                    self.stats["draft_accepted"] += int(acc_np[j, slot])
+                if len(r.generated) >= r.max_new_tokens:
+                    self._finish(r)
 
     def check_invariants(self) -> None:
         """Assert engine/allocator/trie conservation (DESIGN.md §5.5);
@@ -2110,14 +2149,18 @@ class ServeEngine:
         durable, verified chunk boundary.  Returns True while work
         remains — callers interleave ``cancel()`` / ``submit()`` with
         ``step()`` for mid-stream control."""
-        self._sweep_lifecycle()
-        self._admit_wave()
-        if self.slot_req.count(None) < self.slots:
-            (self._run_spec_chunk if self.spec else self._run_chunk)()
-        if self.integrity:
-            self._integrity_sweep()
-        if self.journal is not None:
-            self.journal.flush()
+        with span("serve.step"):
+            with span("serve.sweep"):
+                self._sweep_lifecycle()
+            self._admit_wave()
+            if self.slot_req.count(None) < self.slots:
+                (self._run_spec_chunk if self.spec else self._run_chunk)()
+            if self.integrity:
+                with span("serve.integrity"):
+                    self._integrity_sweep()
+            if self.journal is not None:
+                with span("serve.journal"):
+                    self.journal.flush()
         if (self.cfg.chaos_crash_after_wave > 0
                 and self.stats["admission_waves"]
                 >= self.cfg.chaos_crash_after_wave):
