@@ -121,12 +121,13 @@ def decode_attention_ablation(contexts=(256, 512, 1024), page=16):
         perm = jax.random.permutation(ks[3], n_pages)[: b * P]
         pages = perm.reshape(b, P).astype(jnp.int32)
         lengths = jnp.asarray([t, t - page // 2], jnp.int32)
-        splits = ops.plan_splits(t, page)
+        ppb, splits = ops.paged_plan(page * hkv * d * 4, P)
 
         def gather_kernel(q, kp, vp, pg, ln, s=splits):
             kd = jnp.swapaxes(dense(kp, pg), 1, 2)
             vd = jnp.swapaxes(dense(vp, pg), 1, 2)
-            return ops.decode_attention(q, kd, vd, ln, bkv=page, splits=s)
+            return ops.decode_attention(q, kd, vd, ln, bkv=ppb * page,
+                                        splits=s)
 
         def paged_kernel(q, kp, vp, pg, ln, s=splits):
             return ops.paged_decode_attention(q, kp, vp, pg, ln, splits=s)

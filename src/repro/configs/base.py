@@ -47,19 +47,21 @@ class ModelConfig:
     # shared across slots via a per-slot page table (DESIGN.md §5.2).
     cache_layout: str = "contiguous"   # "contiguous" | "paged"
     kv_page_size: int = 16             # tokens per page ("paged" only)
-    # Decode-attention kernel for the single-token decode step (DESIGN.md
-    # §5.2).  "xla": gather a dense per-slot view and run the masked XLA
-    # softmax (the default, and the prefill path always).  "pallas_paged":
-    # the paged split-KV Pallas kernel dereferences the page table inside
-    # the kernel and reads the pool in place — no gather copy.
-    # "pallas_gather": the same kernel math over the gathered dense view;
-    # this is the bit-identity reference for the paged path and the
-    # gather-cost ablation arm in the benches.
-    decode_kernel: str = "xla"    # "xla" | "pallas_gather" | "pallas_paged"
+    # Decode-attention path for the single-token decode step (DESIGN.md
+    # §5.2).  "auto": ServeEngine resolves it once, at construction — the
+    # paged kernel on a paged cache on a TPU, "xla" otherwise (and outside
+    # the engine).  "xla": gather a dense per-slot view and run the masked
+    # XLA softmax (the prefill path always).  "pallas_paged": the paged
+    # split-KV Pallas kernel reads only the resident pages of the pool, in
+    # place.  "pallas_gather": the same kernel math and compute block over
+    # the gathered dense view; the bit-identity reference for the paged
+    # path.
+    decode_kernel: str = "auto"   # | "xla" | "pallas_gather" | "pallas_paged"
     # Split-K parallelism for the Pallas decode kernels.  0 = planned: the
-    # serve engine bakes its CachePolicyEngine decode plan in here before
-    # building the model (jitted traces need a static split count); direct
-    # model users get kernels.decode_attention.ops.plan_splits' default.
+    # serve engine bakes its plan in here before building the model (jitted
+    # traces need a static split count) — the chip's cores for the paged
+    # kernel, its CachePolicyEngine decode plan for the dense one; direct
+    # model users get kernels.decode_attention.ops' defaults.
     decode_splits: int = 0
     # Prefix sharing (serving, DESIGN.md §5.4): admission attaches a new
     # request to already-resident full prefix pages via the host-side radix

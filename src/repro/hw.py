@@ -20,6 +20,7 @@ class Chip:
     peak_flops_fp32: float = 197e12 / 4  # no fp32 MXU path; conservative
     mxu_dim: int = 128                  # systolic array is 128x128
     vpu_lanes: int = 8 * 128            # (8, 128) vector registers
+    tensorcores: int = 1                # cores a "parallel" grid axis spans
     # Memory
     hbm_bytes: int = 16 * 1024**3       # 16 GB
     hbm_bw: float = 819e9               # B/s

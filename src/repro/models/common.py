@@ -384,9 +384,11 @@ def paged_kv_buffers(lead: tuple, batch: int, max_len: int, cfg,
 
 
 def append_kv_paged(pool: jnp.ndarray, new: jnp.ndarray, lengths: jnp.ndarray,
-                    seg_lens: jnp.ndarray | None,
-                    pages: jnp.ndarray) -> jnp.ndarray:
-    """Scatter a (b, s, hkv, d) block into an (N, page_size, hkv * d) pool.
+                    seg_lens: jnp.ndarray | None, pages: jnp.ndarray,
+                    layer: jnp.ndarray | None = None) -> jnp.ndarray:
+    """Scatter a (b, s, hkv, d) block into an (N, page_size, hkv * d) pool,
+    or, given ``layer``, into that layer's pool of an (L, N, ...) stack, in
+    place.
 
     Row i of slot b lands at logical position ``lengths[b] + i``, translated
     through ``pages`` (b, P) to physical page ``pages[b, pos // page_size]``,
@@ -395,7 +397,7 @@ def append_kv_paged(pool: jnp.ndarray, new: jnp.ndarray, lengths: jnp.ndarray,
     physical page N and are DROPPED by the scatter — the paged twin of
     :func:`append_kv`'s overflow semantics."""
     b, s = new.shape[:2]
-    N, psz = pool.shape[0], pool.shape[1]
+    N, psz = pool.shape[-3], pool.shape[-2]
     P = pages.shape[1]
     pos = lengths[:, None] + jnp.arange(s)[None, :]           # (b, s)
     pi, wi = pos // psz, pos % psz
@@ -405,14 +407,20 @@ def append_kv_paged(pool: jnp.ndarray, new: jnp.ndarray, lengths: jnp.ndarray,
     if valid is not None:
         drop = drop | ~valid
     phys = jnp.where(drop, N, phys)
-    return pool.at[phys.reshape(-1), wi.reshape(-1)].set(
+    at = (phys.reshape(-1), wi.reshape(-1))
+    if layer is not None:
+        at = (layer,) + at
+    return pool.at[at].set(
         new.reshape(b * s, -1).astype(pool.dtype), mode="drop"
     )
 
 
-def gather_pages(pool: jnp.ndarray, pages: jnp.ndarray) -> jnp.ndarray:
+def gather_pages(pool: jnp.ndarray, pages: jnp.ndarray,
+                 layer: jnp.ndarray | None = None) -> jnp.ndarray:
     """(N, page_size, hkv * d) pool + (b, P) table -> dense (b, P*page_size,
-    hkv * d): the contiguous ring's rows, heads still flattened.
+    hkv * d): the contiguous ring's rows, heads still flattened.  Given
+    ``layer``, the pool is that layer's of an (L, N, ...) stack, gathered
+    from the stack directly.
 
     Unmapped entries (-1) clamp to page 0: their content is garbage by
     contract and masked by the caller's ``kv_len``, exactly like the stale
@@ -426,13 +434,16 @@ def gather_pages(pool: jnp.ndarray, pages: jnp.ndarray) -> jnp.ndarray:
     bit-identical rows for the aliased positions, including within the
     admission dispatch that writes them (the scatter's output pool is the
     gather's input, so a same-wave sharer reads the owner's fresh K/V)."""
-    N, psz, hd = pool.shape
+    N, psz, hd = pool.shape[-3:]
     b, P = pages.shape
-    g = jnp.take(pool, jnp.clip(pages, 0, N - 1), axis=0)     # (b, P, psz, hd)
+    idx = jnp.clip(pages, 0, N - 1)
+    if layer is not None:
+        pool, idx = pool.reshape(-1, psz, hd), layer * N + idx
+    g = jnp.take(pool, idx, axis=0)                           # (b, P, psz, hd)
     return g.reshape(b, P * psz, hd)
 
 
-def _decode_step_kernel(q, kc, vc, kv_len, cfg, pages):
+def _decode_step_kernel(q, kc, vc, kv_len, cfg, pages, layer=None):
     """Route the s == 1 decode step through the Pallas split-KV kernels.
 
     A single causal query sits at its slot's cursor, so the causal mask is
@@ -440,38 +451,38 @@ def _decode_step_kernel(q, kc, vc, kv_len, cfg, pages):
     the kernels' per-slot ``lengths`` masking reproduces ``_sdpa``'s
     causal + ``kv_len`` masking exactly.
 
-    ``pallas_paged`` on a paged cache dereferences the page table inside
-    the kernel (no ``gather_pages`` copy — the pool is read in place);
-    any other non-"xla" value (``pallas_gather``) runs the same kernel
-    math over the dense gathered view with the KV block pinned to the
-    page size, which makes it the bit-identity reference for the paged
-    path (see kernels/decode_attention).  On a contiguous cache both fall
-    back to the dense kernel over the ring.
+    ``pallas_paged`` on a paged cache reads only the resident pages of the
+    pool, in place (no ``gather_pages`` copy); ``pallas_gather`` runs the
+    dense kernel over the gathered view with the same compute block
+    (``pages_per_block x page_size`` positions) and splits, which makes it
+    the bit-identity reference for the paged path (see
+    kernels/decode_attention).  On a contiguous cache both fall back to the
+    dense kernel over the ring.
     """
     from repro.kernels.decode_attention import ops as dec_ops
 
     q1 = q[:, 0]                                       # (b, hq, dh)
+    splits = cfg.decode_splits or None
     if pages is not None:
-        psz, n_pages = kc.shape[1], pages.shape[1]
-        splits = max(1, min(
-            cfg.decode_splits or dec_ops.plan_splits(n_pages * psz, psz),
-            n_pages,
-        ))
         if cfg.decode_kernel == "pallas_paged":
             out = dec_ops.paged_decode_attention(
-                q1, kc, vc, pages, kv_len, splits=splits
+                q1, kc, vc, pages, kv_len, layer, splits=splits
             )
         else:
             b, hq, dh = q1.shape
-            kd, vd = (jnp.swapaxes(gather_pages(x, pages).reshape(
+            psz, hd = kc.shape[-2:]
+            n_pages = pages.shape[1]
+            ppb, splits = dec_ops.paged_plan(
+                psz * hd * kc.dtype.itemsize, n_pages, splits)
+            kd, vd = (jnp.swapaxes(gather_pages(x, pages, layer).reshape(
                 b, n_pages * psz, -1, dh), 1, 2) for x in (kc, vc))
             out = dec_ops.decode_attention(
-                q1, kd, vd, kv_len, bkv=psz, splits=splits
+                q1, kd, vd, kv_len, bkv=ppb * psz, splits=splits
             )
     else:
         t = kc.shape[1]
         bkv = min(512, t)
-        splits = cfg.decode_splits or dec_ops.plan_splits(t, bkv)
+        splits = splits or dec_ops.plan_splits(t, bkv)
         out = dec_ops.decode_attention(
             q1, jnp.swapaxes(kc, 1, 2), jnp.swapaxes(vc, 1, 2), kv_len,
             bkv=bkv, splits=splits,
@@ -522,15 +533,17 @@ def apply_attn(
                 # overwritten as the cursor advances.
                 lengths = cache["lengths"]
                 if "pages" in cache:
-                    # Paged pool: scatter through the page table.  The XLA
-                    # path then gathers a dense per-slot view for the masked
-                    # online-softmax; the Pallas decode-step path below reads
-                    # the pool in place instead — no gather copy.
-                    pages = cache["pages"]
+                    # Paged pool: scatter through the page table (into
+                    # layer ``cache["layer"]`` of an (L, N, ...) stack when
+                    # given).  The XLA path then gathers a dense per-slot
+                    # view for the masked online-softmax; the paged
+                    # decode-step kernel below reads only the resident pages
+                    # in place instead.
+                    pages, layer = cache["pages"], cache.get("layer")
                     kc = append_kv_paged(cache["k"], k, lengths,
-                                         seg_lens, pages)
+                                         seg_lens, pages, layer)
                     vc = append_kv_paged(cache["v"], v, lengths,
-                                         seg_lens, pages)
+                                         seg_lens, pages, layer)
                 else:
                     kc = append_kv(cache["k"], k, lengths, seg_lens)
                     vc = append_kv(cache["v"], v, lengths, seg_lens)
@@ -539,13 +552,15 @@ def apply_attn(
                 )
                 new_cache = {"k": kc, "v": vc}
                 q_offset = lengths
-                if cfg.decode_kernel != "xla" and s == 1 and causal:
+                if (cfg.decode_kernel in ("pallas_paged", "pallas_gather")
+                        and s == 1 and causal):
                     kernel_out = _decode_step_kernel(
-                        q, kc, vc, kv_len, cfg, cache.get("pages")
+                        q, kc, vc, kv_len, cfg, cache.get("pages"),
+                        cache.get("layer"),
                     )
                 elif "pages" in cache:
-                    k = gather_pages(kc, pages).reshape(b, -1, hkv, dh)
-                    v = gather_pages(vc, pages).reshape(b, -1, hkv, dh)
+                    k = gather_pages(kc, pages, layer).reshape(b, -1, hkv, dh)
+                    v = gather_pages(vc, pages, layer).reshape(b, -1, hkv, dh)
                 else:
                     k, v = kc, vc
             else:

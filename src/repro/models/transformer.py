@@ -191,6 +191,27 @@ def _stack_cached(params, x, cfg: ModelConfig, positions, vis, cache,
         )
         return x, out_cache(new_cache)
 
+    if pages is not None:
+        # Paged pool: the stacked (L, N, page, hkv * dh) pools ride the
+        # scan's carry, and each layer appends its rows and reads its pages
+        # in place through its layer index — no layer's pool is sliced out
+        # of the stack and written back.
+        def paged_body(carry, inp):
+            h, kp, vp = carry
+            lp, layer = inp
+            c = {"k": kp, "v": vp, "layer": layer, "lengths": lengths,
+                 "pages": pages}
+            h, _, nc = _self_block(lp, h, cfg, positions, cache=c,
+                                   seg_lens=seg_lens)
+            return (h, nc["k"], nc["v"]), None
+
+        kv = cache["layers"]
+        (x, kp, vp), _ = cm.scan(
+            paged_body, (x, kv["k"], kv["v"]),
+            (params["layers"], jnp.arange(kv["k"].shape[0])),
+        )
+        return x, out_cache({"k": kp, "v": vp})
+
     def body(h, inp):
         lp, lc = inp
         h, _, nc = _self_block(

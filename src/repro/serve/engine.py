@@ -185,6 +185,18 @@ def _pad_bucket(n: int, cap: int) -> int:
     return min(b, cap)
 
 
+def resolve_decode_kernel(cfg: ModelConfig) -> str:
+    """The decode-attention path for ``cfg.decode_kernel == "auto"``: the
+    paged kernel, which reads only the resident pages in place, on a paged
+    cache on a TPU; XLA otherwise — on a contiguous cache, and off the chip,
+    where Pallas would only run interpreted.  An explicit path wins."""
+    if cfg.decode_kernel != "auto":
+        return cfg.decode_kernel
+    if cfg.cache_layout == "paged" and jax.default_backend() == "tpu":
+        return "pallas_paged"
+    return "xla"
+
+
 class ServeEngine:
     """Continuous-batching engine over a fixed pool of request slots.
 
@@ -202,6 +214,11 @@ class ServeEngine:
                  max_queue: int | None = None,
                  journal_path: str | None = None,
                  no_progress_limit: int = 256):
+        # Decode-attention path (DESIGN.md §5.2), resolved once from what
+        # the engine observes and baked into the config the model is traced
+        # with, as the split count is below.
+        cfg = dataclasses.replace(cfg,
+                                  decode_kernel=resolve_decode_kernel(cfg))
         self.cfg = cfg
         self.params = params
         self.slots = batch_slots
@@ -407,6 +424,11 @@ class ServeEngine:
             "readmitted": 0,          # preemption-restore re-admissions
             "prefill_work_tokens": 0,  # suffix tokens actually prefilled
             "prefill_rows_computed": 0,  # slots x pad rows the waves ran
+            # Paged decode chunks: pages the paged kernel reads (mapped
+            # pages of the decoding slots) and pages the gather path reads
+            # (every slot's whole table), each x chunk_size.
+            "decode_pages_read": 0,
+            "decode_pages_dense": 0,
             "spec_tokens": 0,         # tokens emitted by draft/verify rounds
             "prefix_hits": 0,         # admissions that attached shared pages
             "prefix_hits_fresh": 0,   # ... the fresh-admission subset
@@ -487,20 +509,32 @@ class ServeEngine:
         ))
 
     def _decode_kernel_splits(self) -> int:
-        """Split-K parallelism for the Pallas decode kernels, planned from
-        ``decode_plan`` (one split per engine-planned KV block) unless the
-        config pins an explicit count.  Paged engines split over logical
-        pages (the kernel's KV block is one page); contiguous ones over
-        the ring."""
-        from repro.kernels.decode_attention.ops import plan_splits
+        """Split-K parallelism for the Pallas decode kernels unless the
+        config pins an explicit count: for the paged kernel, the chip's
+        cores (a split beyond them only adds partials to merge); for the
+        dense kernel over a contiguous ring, ``decode_plan`` (one split per
+        engine-planned KV block)."""
+        from repro.kernels.decode_attention.ops import paged_plan, plan_splits
 
         if self.cfg.decode_splits:
             return self.cfg.decode_splits
         if self.paged:
-            s, bkv = self.pages_per_slot * self.page_size, self.page_size
-        else:
-            s, bkv = self.max_len, min(512, self.max_len)
-        return plan_splits(s, bkv, plan=self.decode_plan)
+            return paged_plan(self._page_bytes(), self.pages_per_slot)[1]
+        return plan_splits(self.max_len, min(512, self.max_len),
+                           plan=self.decode_plan)
+
+    def _page_bytes(self) -> int:
+        return (self.page_size * self.cfg.n_kv_heads * self.cfg.head_dim_
+                * jnp.dtype(self.cfg.dtype).itemsize)
+
+    def _kernel_bkv(self) -> int:
+        """KV positions per compute block of the Pallas decode kernels."""
+        if not self.paged:
+            return min(512, self.max_len)
+        from repro.kernels.decode_attention.ops import paged_plan
+
+        return paged_plan(self._page_bytes(), self.pages_per_slot)[0] \
+            * self.page_size
 
     def policy_report(self) -> dict:
         """Serving-side policy decisions (DESIGN.md §5) + planner counters."""
@@ -611,13 +645,13 @@ class ServeEngine:
                 },
                 "vmem_bytes": self.decode_plan.vmem_bytes,
                 "grid_order": list(self.decode_plan.grid_order),
-                # Which decode-step kernel the model was traced with, and
-                # the split-K count baked from decode_plan (== grid
-                # parallelism of the Pallas kernels when != "xla").
+                # The decode-step path the model was traced with (resolved
+                # at construction), the split-K count baked in (the grid
+                # parallelism of the Pallas kernels when != "xla") and
+                # their compute block.
                 "kernel": self.cfg.decode_kernel,
                 "planned_splits": self.decode_splits,
-                "kernel_bkv": (self.page_size if self.paged
-                               else min(512, self.max_len)),
+                "kernel_bkv": self._kernel_bkv(),
             }
         return report
 
@@ -1541,6 +1575,12 @@ class ServeEngine:
         return nxt
 
     def _run_chunk(self) -> None:
+        if self.paged:
+            live = [slot for slot, _ in self._live()]
+            self.stats["decode_pages_read"] += self.chunk_size * int(
+                (self.page_table[live] >= 0).sum())
+            self.stats["decode_pages_dense"] += (
+                self.chunk_size * self.slots * self.pages_per_slot)
         with span("serve.decode"):
             (self.cache, self.cur_tok, self.remaining, self.tok_idx,
              self.hist, self.hist_len, toks, actives) = self._decode_chunk(

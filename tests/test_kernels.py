@@ -135,15 +135,17 @@ def _paged_case(key, b, hq, hkv, N, psz, P, d, dtype, unmapped_tail=True):
 
 class TestPagedDecodeAttention:
     """The paged kernel's contract: bit-identical to gather_pages + the
-    dense split-KV kernel (same splits, bkv == page_size) — gather's
-    clamp-to-page-0-then-mask semantics are the reference."""
+    dense split-KV kernel with the same compute block (bkv = pages per
+    block x page_size) and splits — gather's clamp-to-page-0-then-mask
+    semantics are the reference."""
 
+    @pytest.mark.parametrize("ppb", [1, 3])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("cfg", [
         (2, 8, 2, 12, 16, 4, 64, 1), (2, 8, 2, 12, 16, 4, 64, 4),
         (3, 4, 4, 9, 8, 5, 32, 2), (1, 16, 4, 20, 16, 8, 128, 3),
     ])
-    def test_bit_identity_vs_gather_path(self, dtype, cfg):
+    def test_bit_identity_vs_gather_path(self, dtype, cfg, ppb):
         from repro.kernels.decode_attention import ops, ref
 
         b, hq, hkv, N, psz, P, d, splits = cfg
@@ -151,10 +153,11 @@ class TestPagedDecodeAttention:
             jax.random.PRNGKey(7), b, hq, hkv, N, psz, P, d, dtype
         )
         got = ops.paged_decode_attention(q, kp, vp, pages, lengths,
-                                         splits=splits)
+                                         splits=splits, pages_per_block=ppb)
         kd = _gather(kp, pages, d)
         vd = _gather(vp, pages, d)
-        want = ops.decode_attention(q, kd, vd, lengths, bkv=psz,
+        ppb, splits = ops.paged_plan(0, P, splits, ppb)
+        want = ops.decode_attention(q, kd, vd, lengths, bkv=ppb * psz,
                                     splits=splits)
         # Bitwise: the paged index-map indirection must change nothing.
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -163,6 +166,79 @@ class TestPagedDecodeAttention:
             np.asarray(got, np.float32), np.asarray(oracle, np.float32),
             **TOL[dtype],
         )
+
+    @pytest.mark.parametrize("ppb", [1, 3])
+    @pytest.mark.parametrize("heads", [(4, 4, 64), (16, 2, 128)],
+                             ids=["mha-d64", "gqa8-d128"])
+    def test_lengths_vs_sdpa_over_gathered_view(self, heads, ppb):
+        """Slots at length 0 (free or parked: finite output, discarded),
+        1, page_size, page_size + 1, a block boundary + 1 and full, with
+        unmapped (-1) tails and pages aliased between slots, against the
+        XLA path it replaces: ``_sdpa_naive`` over the gathered view."""
+        from repro.kernels.decode_attention import ops
+        from repro.models.common import _sdpa_naive, gather_pages
+
+        hq, hkv, d = heads
+        psz, P, N = 16, 7, 20
+        block = ppb * psz
+        lengths = jnp.asarray([0, 1, psz, psz + 1, block + 1, P * psz],
+                              jnp.int32)
+        b = lengths.shape[0]
+        ks = jax.random.split(jax.random.PRNGKey(12), 4)
+        q = _rand(ks[0], (b, hq, d), jnp.bfloat16)
+        kp = _rand(ks[1], (N, psz, hkv * d), jnp.bfloat16)
+        vp = _rand(ks[2], (N, psz, hkv * d), jnp.bfloat16)
+        table = np.array(jax.random.randint(ks[3], (b, P), 0, N))
+        table[2, 0] = table[3, 0] = table[5, 0]    # a shared prefix page
+        mapped = -(-np.asarray(lengths) // psz)
+        table = np.where(np.arange(P)[None] < mapped[:, None], table, -1)
+        pages = jnp.asarray(table, jnp.int32)
+        got = ops.paged_decode_attention(q, kp, vp, pages, lengths,
+                                         pages_per_block=ppb)
+        assert np.isfinite(np.asarray(got, np.float32)).all()
+        kd, vd = (gather_pages(x, pages).reshape(b, P * psz, hkv, d)
+                  for x in (kp, vp))
+        want = _sdpa_naive(q[:, None], kd, vd, causal=True,
+                           q_offset=lengths - 1, kv_len=lengths)[:, 0]
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32)[1:], np.asarray(want, np.float32)[1:],
+            **TOL[jnp.bfloat16],
+        )
+
+    def test_reads_only_resident_pages(self):
+        """Pages past each slot's length — mapped or not — are never read:
+        poisoning them with inf and NaN changes no bit of the output."""
+        from repro.kernels.decode_attention import ops
+
+        b, hq, hkv, N, psz, P, d = 2, 4, 2, 10, 8, 6, 32
+        q, kp, vp, _, _ = _paged_case(
+            jax.random.PRNGKey(13), b, hq, hkv, N, psz, P, d, jnp.float32,
+            unmapped_tail=False,
+        )
+        pages = jnp.asarray([[3, 1, 4, 5, 6, 7], [2, 8, -1, -1, -1, -1]],
+                            jnp.int32)
+        lengths = jnp.asarray([psz + 3, psz], jnp.int32)
+        clean = ops.paged_decode_attention(q, kp, vp, pages, lengths,
+                                           pages_per_block=4)
+        resident = jnp.zeros((N,), bool).at[jnp.asarray([3, 1, 2])].set(True)
+        dirty = ops.paged_decode_attention(
+            q, jnp.where(resident[:, None, None], kp, jnp.inf),
+            jnp.where(resident[:, None, None], vp, jnp.nan), pages, lengths,
+            pages_per_block=4)
+        np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
+
+    def test_block_size_from_page_bytes(self):
+        """One rule, different blocks: minicpm-2b's 72 KiB pages and
+        yi-9b's 16 KiB pages both land at 0.25-1 MiB of K per block."""
+        from repro.kernels.decode_attention.ops import pages_per_block
+
+        minicpm = pages_per_block(16 * 36 * 64 * 2, 128)
+        yi = pages_per_block(16 * 4 * 128 * 2, 128)
+        assert (minicpm, yi) == (8, 32)
+        for ppb, page in ((minicpm, 16 * 36 * 64 * 2), (yi, 16 * 4 * 128 * 2)):
+            assert 256 * 1024 <= ppb * page <= 1024 * 1024
+        assert pages_per_block(16 * 4 * 128 * 2, 5) == 5
+        assert pages_per_block(4 << 20, 128) == 1
 
     def test_aliased_shared_pages(self):
         """Two slots whose tables alias the same physical pages (prefix

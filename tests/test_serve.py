@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.decode_attention.ops import pages_per_block
 from repro.models import build_model, get_config
 from repro.serve.engine import Request, ServeEngine
 
@@ -410,7 +411,12 @@ def test_decode_kernel_paged_bit_identical_to_gather(arch):
     rep = eng.policy_report()["decode_attention"]
     assert rep["kernel"] == "pallas_paged"
     assert rep["planned_splits"] >= 1
-    assert rep["kernel_bkv"] == eng.page_size
+    # Both paths fold the same compute block of several pages.
+    page_bytes = (eng.page_size * eng.cfg.n_kv_heads * eng.cfg.head_dim_
+                  * jnp.dtype(eng.cfg.dtype).itemsize)
+    ppb = pages_per_block(page_bytes, eng.pages_per_slot)
+    assert ppb > 1
+    assert rep["kernel_bkv"] == ppb * eng.page_size
 
 
 def test_decode_kernel_splits_baked_from_plan():
@@ -431,6 +437,58 @@ def test_decode_kernel_splits_baked_from_plan():
     )
     assert pinned.decode_splits == 2
     assert pinned.policy_report()["decode_attention"]["planned_splits"] == 2
+
+
+@pytest.mark.parametrize("layout,backend,want", [
+    ("paged", "cpu", "xla"), ("contiguous", "cpu", "xla"),
+    ("paged", "tpu", "pallas_paged"), ("contiguous", "tpu", "xla"),
+])
+def test_decode_kernel_resolved_at_construction(layout, backend, want,
+                                                monkeypatch):
+    """The default ("auto") decode path is resolved once from what the
+    engine observes: the paged kernel on a paged cache on a TPU, XLA on a
+    contiguous cache or off the chip; the traced config and the policy
+    report carry the resolved path."""
+    cfg = get_config("qwen2.5-32b", smoke=True)
+    assert cfg.decode_kernel == "auto"
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    cfg = dataclasses.replace(cfg, cache_layout=layout, kv_page_size=8)
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: backend)
+        eng = ServeEngine(cfg, params, batch_slots=2, max_len=32)
+    assert eng.cfg.decode_kernel == want
+    assert eng.policy_report()["decode_attention"]["kernel"] == want
+
+
+@pytest.mark.parametrize("explicit", ["xla", "pallas_gather", "pallas_paged"])
+def test_decode_kernel_explicit_wins(explicit, monkeypatch):
+    cfg = dataclasses.replace(_paged(get_config("qwen2.5-32b", smoke=True)),
+                              decode_kernel=explicit)
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        eng = ServeEngine(cfg, params, batch_slots=2, max_len=32)
+    assert eng.cfg.decode_kernel == explicit
+    assert eng.policy_report()["decode_attention"]["kernel"] == explicit
+
+
+def test_decode_pages_counters():
+    """Per decode chunk: the paged kernel reads the decoding slots' mapped
+    pages, the gather path every slot's whole table, each x chunk_size.
+    Slots 2 x 4 pages of 8, chunk 4: A (9 + 8 tokens, 2 pages) and B (3 +
+    4, 1 page) decode one chunk together, then A alone."""
+    cfg = _paged(get_config("qwen2.5-32b", smoke=True))
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    eng = ServeEngine(cfg, params, batch_slots=2, max_len=32, chunk_size=4)
+    rng = np.random.default_rng(3)
+    reqs = [Request(prompt=rng.integers(0, cfg.vocab, size=n).astype(np.int32),
+                    max_new_tokens=m) for n, m in ((9, 8), (3, 4))]
+    eng.run(reqs)
+    stats = eng.serve_stats()
+    assert stats["chunks"] == 2
+    assert stats["decode_pages_read"] == 4 * (2 + 1) + 4 * 2
+    assert stats["decode_pages_dense"] == 2 * 4 * 2 * 4
+    assert stats["decode_pages_read"] <= stats["decode_pages_dense"]
 
 
 def test_paged_pool_oversubscription_mixed_lengths():

@@ -19,9 +19,10 @@ from repro.kernels.decode_attention.decode_attention import (
     decode_attention, paged_decode_attention,
 )
 
-# (hq, hkv, head_dim): minicpm-2b's multi-head attention and yi-9b's GQA.
-WIDTHS = {"minicpm-2b": (36, 36, 64), "yi-9b": (32, 4, 128)}
-SLOTS, PAGE, PAGES_PER_SLOT = 8, 16, 64
+# (hq, hkv, head_dim, slots, pool pages): minicpm-2b's multi-head attention
+# and yi-9b's GQA, at the benchmark's deployments (bench/configs).
+WIDTHS = {"minicpm-2b": (36, 36, 64, 8, 496), "yi-9b": (32, 4, 128, 24, 2400)}
+PAGE, PAGES_PER_SLOT = 16, 128
 
 
 @pytest.fixture(scope="module")
@@ -43,36 +44,36 @@ def _hlo(fn, *shapes):
 
 @pytest.mark.parametrize("arch", sorted(WIDTHS))
 def test_paged_decode_attention_compiles_for_v5e(arch, one_chip):
-    hq, hkv, d = WIDTHS[arch]
-    n = SLOTS * PAGES_PER_SLOT
+    hq, hkv, d, slots, n = WIDTHS[arch]
 
     def s(shape, dt=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    splits = ops.plan_splits(PAGES_PER_SLOT * PAGE, PAGE)
+    ppb, splits = ops.paged_plan(PAGE * hkv * d * 2, PAGES_PER_SLOT)
     hlo = _hlo(
         lambda q, k, v, pg, ln: paged_decode_attention(
-            q, k, v, pg, ln, splits=splits, interpret=False),
-        s((SLOTS, hq, d)), s((n, PAGE, hkv * d)), s((n, PAGE, hkv * d)),
-        s((SLOTS, PAGES_PER_SLOT), jnp.int32), s((SLOTS,), jnp.int32),
+            q, k, v, pg, ln, splits=splits, pages_per_block=ppb,
+            interpret=False),
+        s((slots, hq, d)), s((n, PAGE, hkv * d)), s((n, PAGE, hkv * d)),
+        s((slots, PAGES_PER_SLOT), jnp.int32), s((slots,), jnp.int32),
     )
     assert "tpu_custom_call" in hlo
 
 
 @pytest.mark.parametrize("arch", sorted(WIDTHS))
 def test_decode_attention_compiles_for_v5e(arch, one_chip):
-    hq, hkv, d = WIDTHS[arch]
+    hq, hkv, d, slots, _ = WIDTHS[arch]
     t = PAGES_PER_SLOT * PAGE
 
     def s(shape, dt=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    # bkv == page size: the gather path the paged kernel is identical to.
-    splits = ops.plan_splits(t, PAGE)
+    # The paged kernel's compute block: the gather path it is identical to.
+    ppb, splits = ops.paged_plan(PAGE * hkv * d * 2, PAGES_PER_SLOT)
     hlo = _hlo(
         lambda q, k, v, ln: decode_attention(
-            q, k, v, ln, bkv=PAGE, splits=splits, interpret=False),
-        s((SLOTS, hq, d)), s((SLOTS, hkv, t, d)), s((SLOTS, hkv, t, d)),
-        s((SLOTS,), jnp.int32),
+            q, k, v, ln, bkv=ppb * PAGE, splits=splits, interpret=False),
+        s((slots, hq, d)), s((slots, hkv, t, d)), s((slots, hkv, t, d)),
+        s((slots,), jnp.int32),
     )
     assert "tpu_custom_call" in hlo
