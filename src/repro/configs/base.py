@@ -2,6 +2,32 @@
 from __future__ import annotations
 
 import dataclasses
+import math
+
+FULL = "full_attention"
+SLIDING = "sliding_attention"
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rotary scaling (arXiv:2309.00071) as transformers'
+    ``_compute_yarn_parameters`` reads it from ``rope_parameters``: each
+    frequency blends its extrapolated and its ``factor``-interpolated value
+    by a linear ramp between the correction dimensions of ``beta_fast`` and
+    ``beta_slow`` rotations over ``original_max_position_embeddings``
+    (truncated to whole dimensions), and cos and sin are scaled by
+    ``attention_factor`` (default 0.1 ln(factor) + 1)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 0.0      # 0 -> 0.1 * ln(factor) + 1
+
+    @property
+    def scale(self) -> float:
+        if self.attention_factor:
+            return self.attention_factor
+        return 0.1 * math.log(self.factor) + 1.0 if self.factor > 1 else 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -22,12 +48,27 @@ class ModelConfig:
     norm_kind: str = "rms"           # rms | layer
     act: str = "swiglu"              # swiglu | gelu
     norm_eps: float = 1e-5
-    # MoE
+    # MoE.  The router spans all ``n_experts``; this chip holds the
+    # ``expert_shard``-th of ``expert_shards`` equal index ranges of them
+    # (expert parallelism) and computes only those experts' part of each
+    # token's result (models.common.apply_moe).
     n_experts: int = 0
-    top_k: int = 0
+    experts_per_token: int = 0       # routing top-k (softmax, renormalized)
+    d_expert: int = 0                # an expert's SwiGLU width; 0 -> d_ff
+    expert_shards: int = 1
+    expert_shard: int = 0
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
     moe_dispatch: str = "dense"      # "dense" | "sorted" (capacity-based)
+    # Attention kind of each layer (the published ``layer_types``): FULL or
+    # SLIDING (keys within the last ``sliding_window`` positions); empty ->
+    # every layer full.  The list repeats with a period the layer stack
+    # scans over (``layer_period``).
+    layer_types: tuple = ()
+    sliding_window: int = 0
+    # YaRN on the full layers' rotary frequencies; sliding layers keep the
+    # plain ``rope_theta`` frequencies.  None -> plain everywhere.
+    rope_yarn: YaRN | None = None
     # SSM (Mamba-2)
     ssm_state: int = 0
     ssm_headdim: int = 64
@@ -134,6 +175,29 @@ class ModelConfig:
         return self.head_dim or (self.d_model // max(self.n_heads, 1))
 
     @property
+    def d_expert_(self) -> int:
+        return self.d_expert or self.d_ff
+
+    @property
+    def experts_held(self) -> int:
+        """Experts this chip holds: ``expert_shard``'s equal share."""
+        return self.n_experts // max(1, self.expert_shards)
+
+    @property
+    def layer_period(self) -> tuple:
+        """The shortest repeating unit of ``layer_types`` (FULL alone when
+        no types are given)."""
+        types = tuple(self.layer_types) or (FULL,)
+        n = len(types)
+        return next(types[:p] for p in range(1, n + 1)
+                    if n % p == 0 and types == types[:p] * (n // p))
+
+    @property
+    def windowed(self) -> bool:
+        """Some layer attends only within ``sliding_window`` positions."""
+        return SLIDING in self.layer_types
+
+    @property
     def padded_vocab(self) -> int:
         m = self.vocab_pad_multiple
         return -(-self.vocab // m) * m
@@ -154,7 +218,8 @@ class ModelConfig:
         attn = d * (hq + 2 * hkv) * dh + hq * dh * d
         mlp = (3 if self.act == "swiglu" else 2) * d * f
         if self.family == "moe":
-            mlp = self.n_experts * mlp + d * self.n_experts
+            mlp = (self.experts_held * 3 * d * self.d_expert_
+                   + d * self.n_experts)
         ssm = 0
         if self.family in ("ssm", "hybrid"):
             di, g, ds, h = self.d_inner, self.ssm_groups, self.ssm_state, self.ssm_heads
@@ -192,12 +257,12 @@ class ModelConfig:
         return int(total + emb)
 
     def active_param_count(self) -> int:
-        """Active params per token (MoE: routed top_k + router only)."""
+        """Active params per token (MoE: the routed picks + router only)."""
         if self.family != "moe":
             return self.param_count()
-        d, f = self.d_model, self.d_ff
-        mlp_all = self.n_experts * (3 * d * f)
-        mlp_act = self.top_k * (3 * d * f)
+        d, f = self.d_model, self.d_expert_
+        mlp_all = self.experts_held * (3 * d * f)
+        mlp_act = self.experts_per_token * (3 * d * f)
         return self.param_count() - self.n_layers * (mlp_all - mlp_act)
 
 

@@ -108,14 +108,15 @@ def param_spec(path: str, shape: tuple[int, ...], cfg: ModelConfig,
         return pad((qh, qd, None))
     if "router" in path:
         return pad((None, "model" if cfg.n_experts % tp == 0 else None))
+    held = cfg.experts_held
     if any(k in path for k in ("wg", "wu")):
-        if len(shape) >= 3 and shape[-3] == cfg.n_experts and shape[-2] == d:
+        if len(shape) >= 3 and shape[-3] == held and shape[-2] == d:
             # EP: experts over model (d_ff stays local per expert shard).
-            return pad(("model" if cfg.n_experts % tp == 0 else None, None, None))
+            return pad(("model" if held % tp == 0 else None, None, None))
         return pad((None, "model" if f % tp == 0 else None))
     if "wd" in path:
-        if len(shape) >= 3 and shape[-3] == cfg.n_experts and shape[-1] == d:
-            return pad(("model" if cfg.n_experts % tp == 0 else None, None, None))
+        if len(shape) >= 3 and shape[-3] == held and shape[-1] == d:
+            return pad(("model" if held % tp == 0 else None, None, None))
         return pad(("model" if f % tp == 0 else None, None))
     if "tok" in path or "unembed" in path:
         v = cfg.padded_vocab
@@ -207,6 +208,10 @@ def cache_spec(cfg: ModelConfig, mesh: Mesh, global_batch: int,
     def leaf_spec(path, x):
         p = _path_str(path)
         nd = x.ndim
+        if "window" in p:
+            # Window rings (L, batch * W / page, page, hkv * dh): each slot
+            # owns a contiguous run of pages, so pages shard with the batch.
+            return P(None, b, None, None)
         if p.endswith("/k") or p.endswith("/v") or "/k/" in p or "/v/" in p:
             if "cross" in p:
                 # Fixed-source (enc/vision) KV: small, reused every step —

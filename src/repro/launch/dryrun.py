@@ -186,6 +186,10 @@ def lower_cell(
 
 
 def _layer_unit(cfg) -> int:
+    """Layers per repeating unit: a cross-attention group, a shared-block
+    span, or one period of the layer types."""
+    if cfg.layer_types:
+        return len(cfg.layer_period)
     return cfg.cross_attn_every or cfg.shared_attn_every or 1
 
 
@@ -215,6 +219,8 @@ def counted_metrics(arch: str, shape_name: str, multi_pod: bool, **knobs):
     cfgs = []
     for k in (1, 2):
         c = dc.replace(cfg, n_layers=unit * k)
+        if cfg.layer_types:
+            c = dc.replace(c, layer_types=cfg.layer_period * k)
         if cfg.family == "encdec":
             c = dc.replace(c, enc_layers=k)
         cfgs.append(c)

@@ -6,7 +6,8 @@ for scan).  Weight layouts keep named logical axes so the sharding rules in
 
     attention:  wq (d, hq, dh)   wk/wv (d, hkv, dh)   wo (hq, dh, d)
     mlp:        wg/wu (d, f)     wd (f, d)
-    moe:        router (d, e)    wg/wu (e, d, f)      wd (e, f, d)
+    moe:        router (d, E)    wg/wu (e, d, f)      wd (e, f, d)
+                (E experts routed over, e of them held here)
     embed:      (v, d)           unembed (d, v)
 
 The XLA path (these functions) is what trains and what the dry-run lowers;
@@ -16,12 +17,14 @@ validated against the same math.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import FULL, SLIDING, ModelConfig, YaRN
 
 Params = dict[str, Any]
 
@@ -90,16 +93,44 @@ def apply_norm(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (b, s, h, dh); positions: (b, s) or (s,)."""
+def yarn_inv_freq(dh: int, theta: float, yarn: YaRN) -> np.ndarray:
+    """YaRN's (half,) inverse frequencies, as transformers'
+    ``_compute_yarn_parameters`` computes them with ``truncate`` on."""
+    pos_freqs = theta ** (np.arange(0, dh, 2, dtype=np.float64) / dh)
+    extrapolated, interpolated = 1.0 / pos_freqs, 1.0 / (yarn.factor * pos_freqs)
+
+    def correction_dim(rotations):
+        return (dh * math.log(yarn.original_max_position_embeddings
+                              / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), dh - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dh // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp              # share of the extrapolated frequency
+    return (interpolated * ramp + extrapolated * keep).astype(np.float32)
+
+
+def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+         yarn: YaRN | None = None) -> jnp.ndarray:
+    """x: (b, s, h, dh); positions: (b, s) or (s,).  With ``yarn`` the
+    frequencies are YaRN's and cos and sin carry its attention factor."""
     dh = x.shape[-1]
     half = dh // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(yarn_inv_freq(dh, theta, yarn))
     if positions.ndim == 1:
         positions = positions[None, :]
     ang = positions[..., None].astype(jnp.float32) * freqs  # (b, s, half)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn.scale, sin * yarn.scale
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
@@ -136,6 +167,11 @@ def attn_init(key, cfg: ModelConfig, kv_d_model: int | None = None) -> Params:
 # is huge even though s=1.
 _SDPA_CHUNK_THRESHOLD = 4096 * 2048
 _SDPA_DECODE_T = 8192
+# Float32 logits above this many bytes in all (batch x heads x s x t)
+# cannot sit beside a chip's weights and cache: they go blocked too, and
+# each block of the blocked path stays under _SDPA_BLOCK_BYTES.
+_SDPA_LOGITS_BYTES = 5 << 30
+_SDPA_BLOCK_BYTES = 512 << 20
 
 
 def _ambient_mesh():
@@ -183,8 +219,13 @@ def _offset_rows(q_offset) -> jnp.ndarray:
     return off[None] if off.ndim == 0 else off
 
 
-def _sdpa_naive(q, k, v, causal: bool, q_offset, kv_len=None):
-    """q: (b, s, hq, dh); k/v: (b, t, hkv, dh). fp32 softmax."""
+def _sdpa_naive(q, k, v, causal: bool, q_offset, kv_len=None, window=0,
+                kv_start=None):
+    """q: (b, s, hq, dh); k/v: (b, t, hkv, dh). fp32 softmax.
+
+    Key j is visible to the query at position i = q_offset + row when
+    j <= i (causal), i - j < window (a sliding window, when set),
+    kv_start[b] <= j (keys below it hold nothing yet) and j < kv_len[b]."""
     b, s, hq, dh = q.shape
     t, hkv = k.shape[1], k.shape[2]
     group = hq // hkv
@@ -196,34 +237,52 @@ def _sdpa_naive(q, k, v, causal: bool, q_offset, kv_len=None):
         qi = off[:, None, None] + jnp.arange(s)[None, :, None]
         ki = jnp.arange(t)[None, None, :]
         mask = ki <= qi                                       # (B, s, t)
+        if window:
+            mask = mask & (qi - ki < window)
         logits = jnp.where(mask[:, None, None], logits, -1e30)
     if kv_len is not None:
         mask = jnp.arange(t)[None, :] < kv_len[:, None]          # (b, t)
+        logits = jnp.where(mask[:, None, None, None, :], logits, -1e30)
+    if kv_start is not None:
+        mask = jnp.arange(t)[None, :] >= kv_start[:, None]       # (b, t)
         logits = jnp.where(mask[:, None, None, None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhgst,bthd->bshgd", probs, v.astype(jnp.float32))
     return out.reshape(b, s, hq, dh).astype(q.dtype)
 
 
-def _chunk_sizes(s: int, t: int) -> tuple[int, int]:
-    """Block shapes bounding both the live logits buffer (b*heads*qb*ck)
-    and the loop trip count (<= ~8x8)."""
+def _chunk_sizes(s: int, t: int, rows: int = 1) -> tuple[int, int]:
+    """Block shapes bounding both the live logits buffer (rows*qb*ck, rows
+    = batch x heads) and the loop trip count (<= ~8x8)."""
     qb = min(s, max(1024, -(-s // 8)))
     ck = min(t, max(1024, -(-t // 8)))
+    while rows * qb * ck * 4 > _SDPA_BLOCK_BYTES and max(qb, ck) > 128:
+        if qb >= ck:
+            qb = -(-qb // 2)
+        else:
+            ck = -(-ck // 2)
     return qb, ck
 
 
 def _sdpa_chunked(q, k, v, causal: bool, q_offset, kv_len=None,
                   chunk: int | None = None, q_block: int | None = None,
-                  shard_rows: bool = False):
-    """Blocked online-softmax attention: outer scan over q blocks, inner
-    scan over KV chunks.  ``shard_rows`` hints GSPMD to reduce-scatter the
-    per-chunk logits over `model` along q rows (used when heads are not
-    TP-shardable, e.g. minicpm/whisper/qwen head counts)."""
+                  shard_rows: bool = False, window: int = 0, kv_start=None):
+    """Blocked online-softmax attention: outer loop over q blocks, inner
+    scan over KV chunks; masks as :func:`_sdpa_naive`.  ``shard_rows``
+    hints GSPMD to reduce-scatter the per-chunk logits over `model` along q
+    rows (used when heads are not TP-shardable, e.g. minicpm/whisper/qwen
+    head counts).
+
+    Chunks no query can see are skipped, not masked: with a window and a
+    static ``q_offset`` each q block scans only the chunks its window and
+    the causal edge reach, and a chunk wholly at or past every slot's
+    ``kv_len`` (or wholly below every ``kv_start``) is passed over at run
+    time.  A skipped chunk would have folded nothing in, so no bit
+    changes."""
     b, s, hq, dh = q.shape
     t, hkv = k.shape[1], k.shape[2]
     group = hq // hkv
-    qb, ck = _chunk_sizes(s, t)
+    qb, ck = _chunk_sizes(s, t, b * hq)
     if chunk is not None:
         ck = chunk
     if q_block is not None:
@@ -243,14 +302,17 @@ def _sdpa_chunked(q, k, v, causal: bool, q_offset, kv_len=None,
     vc = jnp.moveaxis(v.astype(jnp.float32).reshape(b, nc, ck, hkv, dh), 1, 0)
     valid = kv_len if kv_len is not None else jnp.full((b,), t)
     off = _offset_rows(q_offset)                              # (1,) or (b,)
+    # Run-time skip bounds: chunks outside [lo_chunk, hi_chunk) hold no
+    # visible key for any slot.
+    hi_chunk = (jnp.max(valid) + ck - 1) // ck
+    lo_chunk = (jnp.min(kv_start) // ck if kv_start is not None
+                else jnp.int32(0))
 
-    def q_body(_, q_in):
-        qblk, iq = q_in                                       # (b, qb, hkv, g, dh)
+    def q_block_out(qblk, iq, kcs, vcs, j0):
         qi = iq * qb + jnp.arange(qb)[None, :, None] + off[:, None, None]
 
-        def kv_body(carry, inp):
+        def fold(carry, kj, vj, j):
             m_prev, l_prev, acc = carry
-            kj, vj, j = inp
             ki = j * ck + jnp.arange(ck)[None, :]             # (1, ck)
             logits = jnp.einsum("bshgd,bthd->bhgst", qblk, kj)
             logits = _maybe_shard(
@@ -258,8 +320,12 @@ def _sdpa_chunked(q, k, v, causal: bool, q_offset, kv_len=None,
                          None),
             )
             mask = ki[None] < valid[:, None, None]            # (b, 1, ck)
+            if kv_start is not None:
+                mask = mask & (ki[None] >= kv_start[:, None, None])
             if causal:
                 mask = mask & (ki[None] <= qi)                # (b, qb, ck)
+                if window:
+                    mask = mask & (qi - ki[None] < window)
             logits = jnp.where(mask[:, None, None], logits, -1e30)
             m_cur = jnp.maximum(m_prev, jnp.max(logits, axis=-1))
             alpha = jnp.exp(m_prev - m_cur)
@@ -268,25 +334,47 @@ def _sdpa_chunked(q, k, v, causal: bool, q_offset, kv_len=None,
             acc = acc * alpha[..., None] + jnp.einsum(
                 "bhgst,bthd->bhgsd", p, vj
             )
-            return (m_cur, l_cur, acc), None
+            return m_cur, l_cur, acc
+
+        def kv_body(carry, inp):
+            kj, vj, j = inp
+            if kv_len is None and kv_start is None:
+                return fold(carry, kj, vj, j), None
+            live = (j < hi_chunk) & (j >= lo_chunk)
+            return jax.lax.cond(live, lambda c: fold(c, kj, vj, j),
+                                lambda c: c, carry), None
 
         m0 = jnp.full((b, hkv, group, qb), -1e30, jnp.float32)
         l0 = jnp.zeros((b, hkv, group, qb), jnp.float32)
         acc0 = jnp.zeros((b, hkv, group, qb, dh), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(
-            kv_body, (m0, l0, acc0), (kc, vc, jnp.arange(nc))
+            kv_body, (m0, l0, acc0),
+            (kcs, vcs, j0 + jnp.arange(kcs.shape[0])),
         )
-        out = acc / jnp.maximum(l, 1e-30)[..., None]          # (b,hkv,g,qb,dh)
-        return None, out
+        return acc / jnp.maximum(l, 1e-30)[..., None]         # (b,hkv,g,qb,dh)
 
-    _, outs = jax.lax.scan(q_body, None, (qf, jnp.arange(nq)))
+    if window and causal and isinstance(q_offset, int):
+        # Static reach of each q block: keys (first - window, last].
+        outs = []
+        for iq in range(nq):
+            first = q_offset + iq * qb
+            j0 = max(0, first - window + 1) // ck
+            j1 = min(nc, (first + qb - 1) // ck + 1)
+            outs.append(q_block_out(qf[iq], iq, kc[j0:j1], vc[j0:j1], j0))
+        outs = jnp.stack(outs)
+    else:
+        _, outs = jax.lax.scan(
+            lambda _, q_in: (None, q_block_out(q_in[0], q_in[1], kc, vc, 0)),
+            None, (qf, jnp.arange(nq)),
+        )
     # (nq, b, hkv, g, qb, dh) -> (b, s, hq, dh)
     outs = jnp.moveaxis(outs, 0, 1).reshape(b, nq, hq, qb, dh)
     outs = jnp.moveaxis(outs, 2, 3).reshape(b, nq * qb, hq, dh)
     return outs[:, :s].astype(q.dtype)
 
 
-def _sdpa(q, k, v, causal: bool, q_offset, kv_len=None):
+def _sdpa(q, k, v, causal: bool, q_offset, kv_len=None, window=0,
+          kv_start=None):
     b, s, hq, dh = q.shape
     t = k.shape[1]
     # Row-shard the logits when heads cannot be TP-sharded (minicpm 36H,
@@ -294,10 +382,12 @@ def _sdpa(q, k, v, causal: bool, q_offset, kv_len=None):
     # instead of all-reducing, bounding the per-chip buffer.
     tp = _ambient_model_axis()
     shard_rows = tp is not None and hq % tp != 0
-    if s * t > _SDPA_CHUNK_THRESHOLD or t > _SDPA_DECODE_T:
+    if (s * t > _SDPA_CHUNK_THRESHOLD or t > _SDPA_DECODE_T
+            or b * hq * s * t * 4 > _SDPA_LOGITS_BYTES):
         return _sdpa_chunked(q, k, v, causal, q_offset, kv_len,
-                             shard_rows=shard_rows)
-    return _sdpa_naive(q, k, v, causal, q_offset, kv_len)
+                             shard_rows=shard_rows, window=window,
+                             kv_start=kv_start)
+    return _sdpa_naive(q, k, v, causal, q_offset, kv_len, window, kv_start)
 
 
 def seg_mask(s: int, seg_lens: jnp.ndarray | None) -> jnp.ndarray | None:
@@ -320,22 +410,26 @@ def last_valid_slice(x: jnp.ndarray, seg_lens: jnp.ndarray | None) -> jnp.ndarra
 
 
 def append_kv(cache_kv: jnp.ndarray, new: jnp.ndarray, lengths: jnp.ndarray,
-              seg_lens: jnp.ndarray | None) -> jnp.ndarray:
-    """Scatter a (b, s, ...) block into a (b, S, ...) ring at per-slot cursors.
+              seg_lens: jnp.ndarray | None,
+              layer: jnp.ndarray | None = None) -> jnp.ndarray:
+    """Scatter a (b, s, ...) block into a (b, S, ...) ring at per-slot
+    cursors, or, given ``layer``, into that layer's ring of an (L, b, S,
+    ...) stack, in place.
 
     Row i of slot b lands at position lengths[b] + i.  Invalid rows
     (i >= seg_lens[b]) and overflow (pos >= S) are redirected out of bounds
     and DROPPED by the scatter — padding never lands in the cache and a
     full slot can never clobber its own valid tail."""
     b, s = new.shape[:2]
-    S = cache_kv.shape[1]
+    S = cache_kv.shape[-3]
     pos = lengths[:, None] + jnp.arange(s)[None, :]           # (b, s)
     valid = seg_mask(s, seg_lens)
     if valid is not None:
         pos = jnp.where(valid, pos, S)
-    return cache_kv.at[jnp.arange(b)[:, None], pos].set(
-        new.astype(cache_kv.dtype), mode="drop"
-    )
+    at = (jnp.arange(b)[:, None], pos)
+    if layer is not None:
+        at = (layer,) + at
+    return cache_kv.at[at].set(new.astype(cache_kv.dtype), mode="drop")
 
 
 # ---------------------------------------------------------------------------
@@ -443,6 +537,96 @@ def gather_pages(pool: jnp.ndarray, pages: jnp.ndarray,
     return g.reshape(b, P * psz, hd)
 
 
+# ---------------------------------------------------------------------------
+# Window ring (DESIGN.md §5.2): a sliding layer's keys live in a per-slot
+# ring of exactly ``sliding_window`` (W) positions — position p at row
+# p % W — laid out as pages of the paged pool, (L, b * W / page_size,
+# page_size, hkv * head_dim), with a fixed table: slot b owns pages
+# [b * W / page_size, (b + 1) * W / page_size).  Rows [0, min(len, W)) of a
+# slot always hold its last min(len, W) positions, so a decode step reads
+# the ring in place with ``kv_len = min(len + 1, W)``: the paged kernel, or
+# the gather path, unchanged (softmax is blind to row order).  The ring
+# never grows, whatever the context: W positions per slot and layer.
+# ---------------------------------------------------------------------------
+
+def window_ring_buffers(lead: tuple, batch: int, cfg) -> Params:
+    """Zeroed window rings with leading stack axes ``lead``."""
+    psz, hd = cfg.kv_page_size, cfg.n_kv_heads * cfg.head_dim_
+    shape = (*lead, batch * cfg.sliding_window // psz, psz, hd)
+    dt = jnp.dtype(cfg.dtype)
+    return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+
+
+def ring_table(batch: int, window: int, page_size: int) -> jnp.ndarray:
+    """(b, W / page_size) page table of the window rings."""
+    n = window // page_size
+    return jnp.arange(batch * n, dtype=jnp.int32).reshape(batch, n)
+
+
+def append_ring(ring: jnp.ndarray, new: jnp.ndarray, lengths: jnp.ndarray,
+                seg_lens: jnp.ndarray | None, window: int,
+                layer: jnp.ndarray | None = None) -> jnp.ndarray:
+    """Scatter a (b, s, hkv, d) block into the slots' window rings (of
+    layer ``layer`` of a stack), in place: position p of slot b at row
+    p % W.  Only each slot's last W valid rows are written, so no two rows
+    of one scatter meet; invalid rows drop."""
+    b, s = new.shape[:2]
+    N, psz = ring.shape[-3], ring.shape[-2]
+    per_slot = window // psz
+    i = jnp.arange(s)[None, :]
+    seg = jnp.full((b,), s) if seg_lens is None else seg_lens
+    keep = (i < seg[:, None]) & (i >= seg[:, None] - window)
+    r = (lengths[:, None] + i) % window                       # (b, s)
+    phys = jnp.where(keep, jnp.arange(b)[:, None] * per_slot + r // psz, N)
+    at = (phys.reshape(-1), (r % psz).reshape(-1))
+    if layer is not None:
+        at = (layer,) + at
+    return ring.at[at].set(
+        new.reshape(b * s, -1).astype(ring.dtype), mode="drop"
+    )
+
+
+def _window_attn(q, k, v, cache, cfg, seg_lens, window):
+    """Sliding-window attention against the slots' rings; returns (out,
+    new ring).
+
+    Decode (s == 1): append, then attend over the ring in place with
+    ``kv_len = min(len + 1, W)``.  A block (prefill): the ring's W rows in
+    position order (key j is position ``len - W + j``; negative positions
+    are masked by ``kv_start``) and then the block, so query i sits at key
+    index W + i and sees keys (i, W + i]: the blocked path skips every
+    chunk of keys outside that reach.  The block's last W valid rows then
+    replace the ring's oldest."""
+    b, s, hq, dh = q.shape
+    hkv = k.shape[2]
+    lengths, layer = cache["lengths"], cache.get("layer")
+    table = ring_table(b, window, cfg.kv_page_size)
+    seg = jnp.full((b,), s, jnp.int32) if seg_lens is None else seg_lens
+    if s == 1:
+        kc = append_ring(cache["k"], k, lengths, seg_lens, window, layer)
+        vc = append_ring(cache["v"], v, lengths, seg_lens, window, layer)
+        kv_len = jnp.minimum(lengths + seg, window)
+        if cfg.decode_kernel in ("pallas_paged", "pallas_gather"):
+            out = _decode_step_kernel(q, kc, vc, kv_len, cfg, table, layer)
+        else:
+            kd, vd = (gather_pages(x, table, layer).reshape(b, window, hkv, dh)
+                      for x in (kc, vc))
+            out = _sdpa(q, kd, vd, causal=False, q_offset=0, kv_len=kv_len)
+        return out, {"k": kc, "v": vc}
+    order = (lengths[:, None] + jnp.arange(window)[None, :]) % window
+    old_k, old_v = (jnp.take_along_axis(
+        gather_pages(x, table, layer), order[..., None], axis=1
+    ).reshape(b, window, hkv, dh) for x in (cache["k"], cache["v"]))
+    keys = jnp.concatenate([old_k, k.astype(old_k.dtype)], axis=1)
+    vals = jnp.concatenate([old_v, v.astype(old_v.dtype)], axis=1)
+    out = _sdpa_chunked(q, keys, vals, causal=True, q_offset=window,
+                        kv_len=window + seg, window=window,
+                        kv_start=window - lengths)
+    kc = append_ring(cache["k"], k, lengths, seg_lens, window, layer)
+    vc = append_ring(cache["v"], v, lengths, seg_lens, window, layer)
+    return out, {"k": kc, "v": vc}
+
+
 def _decode_step_kernel(q, kc, vc, kv_len, cfg, pages, layer=None):
     """Route the s == 1 decode step through the Pallas split-KV kernels.
 
@@ -480,6 +664,8 @@ def _decode_step_kernel(q, kc, vc, kv_len, cfg, pages, layer=None):
                 q1, kd, vd, kv_len, bkv=ppb * psz, splits=splits
             )
     else:
+        if layer is not None:
+            kc, vc = kc[layer], vc[layer]
         t = kc.shape[1]
         bkv = min(512, t)
         splits = splits or dec_ops.plan_splits(t, bkv)
@@ -500,7 +686,13 @@ def apply_attn(
     causal: bool = True,
     use_rope: bool = True,
     seg_lens: jnp.ndarray | None = None,  # (b,) valid new tokens per slot
+    kind: str = FULL,                 # the layer's attention kind
 ) -> tuple[jnp.ndarray, Params | None]:
+    """Self- or cross-attention.  A SLIDING layer sees the last
+    ``cfg.sliding_window`` positions (its cache is a window ring); a FULL
+    layer takes ``cfg.rope_yarn``'s frequencies, if any.  A cache with a
+    ``layer`` index holds every layer's K/V of its kind stacked, updated in
+    place."""
     b, s, d = x.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
     src = x if kv_src is None else kv_src
@@ -512,12 +704,18 @@ def apply_attn(
         k = k + p["bk"]
         v = v + p["bv"]
     if use_rope and kv_src is None:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        yarn = cfg.rope_yarn if kind == FULL else None
+        q = rope(q, positions, cfg.rope_theta, yarn)
+        k = rope(k, positions, cfg.rope_theta, yarn)
+    window = cfg.sliding_window if kind == SLIDING else 0
 
     # KV append, page gather or paged kernel, and the attention math:
     # what a change of decode-attention implementation replaces.
     with jax.named_scope("attn"):
+        if window and cache is not None:
+            out, new_cache = _window_attn(q, k, v, cache, cfg, seg_lens,
+                                          window)
+            return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), new_cache
         new_cache = None
         kv_len = None
         kernel_out = None
@@ -545,8 +743,9 @@ def apply_attn(
                     vc = append_kv_paged(cache["v"], v, lengths,
                                          seg_lens, pages, layer)
                 else:
-                    kc = append_kv(cache["k"], k, lengths, seg_lens)
-                    vc = append_kv(cache["v"], v, lengths, seg_lens)
+                    layer = cache.get("layer")
+                    kc = append_kv(cache["k"], k, lengths, seg_lens, layer)
+                    vc = append_kv(cache["v"], v, lengths, seg_lens, layer)
                 kv_len = lengths + (
                     jnp.int32(s) if seg_lens is None else seg_lens
                 )
@@ -561,6 +760,8 @@ def apply_attn(
                 elif "pages" in cache:
                     k = gather_pages(kc, pages, layer).reshape(b, -1, hkv, dh)
                     v = gather_pages(vc, pages, layer).reshape(b, -1, hkv, dh)
+                elif layer is not None:
+                    k, v = kc[layer], vc[layer]
                 else:
                     k, v = kc, vc
             else:
@@ -572,7 +773,7 @@ def apply_attn(
             out = kernel_out
         else:
             out = _sdpa(q, k, v, causal=causal and not is_cross,
-                        q_offset=q_offset, kv_len=kv_len)
+                        q_offset=q_offset, kv_len=kv_len, window=window)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return y, new_cache
 
@@ -607,15 +808,23 @@ def apply_mlp(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# MoE (top-k routing, capacity-based dense dispatch — GShard style)
+# MoE (top-k routing over every expert; this chip's experts computed)
 # ---------------------------------------------------------------------------
 
+# Token rows per block of the dense expert dispatch: a prefill wave's rows
+# go through the held experts a block at a time, so its (experts, rows,
+# d_expert) activations stay a few hundred MB.
+_MOE_ROW_BLOCK = 4096
+
+
 def moe_init(key, cfg: ModelConfig) -> Params:
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """The router over all ``n_experts``; the weights of the experts this
+    chip holds (``experts_held`` of them)."""
+    d, f, e = cfg.d_model, cfg.d_expert_, cfg.experts_held
     dt = jnp.dtype(cfg.dtype)
     ks = jax.random.split(key, 4)
     return {
-        "router": dense_init(ks[0], (d, e), d, jnp.float32),
+        "router": dense_init(ks[0], (d, cfg.n_experts), d, jnp.float32),
         "wg": dense_init(ks[1], (e, d, f), d, dt),
         "wu": dense_init(ks[2], (e, d, f), d, dt),
         "wd": dense_init(ks[3], (e, f, d), f, dt),
@@ -623,7 +832,8 @@ def moe_init(key, cfg: ModelConfig) -> Params:
 
 
 def _route(p, xf, cfg: ModelConfig):
-    e, k = cfg.n_experts, cfg.top_k
+    """float32 softmax over every expert's logit, top-k, renormalized."""
+    e, k = cfg.n_experts, cfg.experts_per_token
     logits = (xf.astype(jnp.float32)) @ p["router"]          # (t, e)
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, idx = jax.lax.top_k(probs, k)                  # (t, k)
@@ -635,66 +845,103 @@ def _route(p, xf, cfg: ModelConfig):
     return gate_vals, idx, onehot, aux
 
 
-def apply_moe(p: Params, x: jnp.ndarray, cfg: ModelConfig):
-    """Returns (y, aux_loss).
+def _held_experts(p, xf, combine):
+    """sum_e combine[t, e] * expert_e(x_t) over the held experts, every
+    row through every expert (a row's unpicked experts get weight 0)."""
+    e = combine.shape[1]
+
+    def rows(xb, cb):
+        xe = jnp.broadcast_to(xb[None], (e,) + xb.shape)      # (e, t, d)
+        h = jax.nn.silu(jnp.einsum("etd,edf->etf", xe, p["wg"])) * jnp.einsum(
+            "etd,edf->etf", xe, p["wu"]
+        )
+        h = h * cb.T[:, :, None].astype(h.dtype)
+        return jnp.einsum("etf,efd->td", h, p["wd"])
+
+    t, d = xf.shape
+    if t <= _MOE_ROW_BLOCK:
+        return rows(xf, combine)
+    n = -(-t // _MOE_ROW_BLOCK)
+    pad = n * _MOE_ROW_BLOCK - t
+    xb = jnp.pad(xf, ((0, pad), (0, 0))).reshape(n, _MOE_ROW_BLOCK, d)
+    cb = jnp.pad(combine, ((0, pad), (0, 0))).reshape(n, _MOE_ROW_BLOCK, e)
+    y = jax.lax.map(lambda a: rows(*a), (xb, cb))
+    return y.reshape(n * _MOE_ROW_BLOCK, d)[:t]
+
+
+def apply_moe(p: Params, x: jnp.ndarray, cfg: ModelConfig,
+              valid: jnp.ndarray | None = None):
+    """Returns (y, aux_loss, picks_held).
+
+    The router scores all ``cfg.n_experts`` experts; this chip holds the
+    ``expert_shard``-th of ``expert_shards`` index ranges of them and ``y``
+    is those experts' part of each token's result (the whole result when
+    one shard holds them all).  ``picks_held`` counts the (token, expert)
+    picks of the ``valid`` (b, s) tokens that land on held experts.
 
     Two dispatch schedules (cfg.moe_dispatch):
 
-    * ``dense`` — one-hot masked einsum: every expert processes every
-      token.  Simple and robust under pjit, but compute scales with E
-      (useful-FLOP ratio ~ top_k/E: 0.125 for olmoe).  This is the
-      paper-faithful baseline.
+    * ``dense`` — every held expert processes every token, weighted by the
+      token's renormalized gate for it (0 where unpicked): exact, and
+      compute scales with the experts held (useful share ~ picks held /
+      experts held: top_k/E, 0.125 for olmoe).  A wide block goes through
+      ``_MOE_ROW_BLOCK`` rows at a time.
     * ``sorted`` — capacity-based sorted dispatch (MegaBlocks/GShard
       style): (token, slot) pairs sort by expert, gather into (E, C, d)
       capacity buffers, batched expert GEMM, scatter back.  Compute
       scales with top_k * capacity_factor — the E/(k*cf) FLOP cut the A4
       §Perf iteration quantifies.  Tokens overflowing an expert's
-      capacity are dropped (standard GShard semantics).
+      capacity are dropped (standard GShard semantics).  Whole layers
+      only (one shard).
     """
-    b, s, d = x.shape
-    e, k = cfg.n_experts, cfg.top_k
-    xf = x.reshape(b * s, d)
-    t = b * s
-    gate_vals, idx, onehot, aux = _route(p, xf, cfg)
+    with jax.named_scope("moe"):
+        b, s, d = x.shape
+        e, k = cfg.n_experts, cfg.experts_per_token
+        held = cfg.experts_held
+        first = cfg.expert_shard * held
+        xf = x.reshape(b * s, d)
+        t = b * s
+        gate_vals, idx, onehot, aux = _route(p, xf, cfg)
+        mine = (idx >= first) & (idx < first + held)          # (t, k)
+        if valid is not None:
+            mine = mine & valid.reshape(t, 1)
+        picks = jnp.sum(mine, dtype=jnp.int32)
 
-    if cfg.moe_dispatch == "sorted":
-        cap = int(-(-t * k * cfg.capacity_factor // e))
-        cap = min(max(128, -(-cap // 128) * 128), t * k)
-        eidx = idx.reshape(-1)                                # (t*k,)
-        gates = gate_vals.reshape(-1).astype(jnp.float32)
-        tok = jnp.repeat(jnp.arange(t), k)
-        order = jnp.argsort(eidx)                             # stable
-        eidx_s, tok_s, gate_s = eidx[order], tok[order], gates[order]
-        counts = jnp.bincount(eidx, length=e)
-        starts = jnp.cumsum(counts) - counts                  # exclusive
-        pos = jnp.arange(t * k) - starts[eidx_s]              # rank in expert
-        keep = pos < cap
-        pos_c = jnp.where(keep, pos, 0)
-        eidx_c = jnp.where(keep, eidx_s, 0)
-        xe = jnp.zeros((e, cap, d), x.dtype).at[
-            eidx_c, pos_c
-        ].add(xf[tok_s] * keep[:, None].astype(x.dtype))      # (e, C, d)
-        h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, p["wg"])) * jnp.einsum(
-            "ecd,edf->ecf", xe, p["wu"]
-        )
-        ye = jnp.einsum("ecf,efd->ecd", h, p["wd"])           # (e, C, d)
-        contrib = ye[eidx_c, pos_c] * (
-            gate_s * keep.astype(jnp.float32)
-        )[:, None].astype(x.dtype)
-        y = jnp.zeros((t, d), x.dtype).at[tok_s].add(contrib)
-        return y.reshape(b, s, d), aux
+        if cfg.moe_dispatch == "sorted":
+            if held != e:
+                raise ValueError("sorted MoE dispatch needs every expert "
+                                 "on one shard")
+            cap = int(-(-t * k * cfg.capacity_factor // e))
+            cap = min(max(128, -(-cap // 128) * 128), t * k)
+            eidx = idx.reshape(-1)                            # (t*k,)
+            gates = gate_vals.reshape(-1).astype(jnp.float32)
+            tok = jnp.repeat(jnp.arange(t), k)
+            order = jnp.argsort(eidx)                         # stable
+            eidx_s, tok_s, gate_s = eidx[order], tok[order], gates[order]
+            counts = jnp.bincount(eidx, length=e)
+            starts = jnp.cumsum(counts) - counts              # exclusive
+            pos = jnp.arange(t * k) - starts[eidx_s]          # rank in expert
+            keep = pos < cap
+            pos_c = jnp.where(keep, pos, 0)
+            eidx_c = jnp.where(keep, eidx_s, 0)
+            xe = jnp.zeros((e, cap, d), x.dtype).at[
+                eidx_c, pos_c
+            ].add(xf[tok_s] * keep[:, None].astype(x.dtype))  # (e, C, d)
+            h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, p["wg"])) * (
+                jnp.einsum("ecd,edf->ecf", xe, p["wu"]))
+            ye = jnp.einsum("ecf,efd->ecd", h, p["wd"])       # (e, C, d)
+            contrib = ye[eidx_c, pos_c] * (
+                gate_s * keep.astype(jnp.float32)
+            )[:, None].astype(x.dtype)
+            y = jnp.zeros((t, d), x.dtype).at[tok_s].add(contrib)
+            return y.reshape(b, s, d), aux, picks
 
-    # Dispatch with the 0/1 mask, combine with the gates POST-expert
-    # (y = sum_i g_i * expert_i(x) — standard MoE semantics).
-    mask = jnp.max(onehot, axis=1)                            # (t, e) in {0,1}
-    combine = jnp.einsum("tk,tke->te", gate_vals, onehot)     # (t, e)
-    xe = jnp.einsum("te,td->etd", mask.astype(x.dtype), xf)   # (e, t, d)
-    h = jax.nn.silu(jnp.einsum("etd,edf->etf", xe, p["wg"])) * jnp.einsum(
-        "etd,edf->etf", xe, p["wu"]
-    )
-    ye = jnp.einsum("etf,efd->etd", h, p["wd"])               # (e, t, d)
-    y = jnp.einsum("etd,te->td", ye, combine.astype(x.dtype))
-    return y.reshape(b, s, d).astype(x.dtype), aux
+        # The gates post-expert (y = sum_i g_i * expert_i(x), standard MoE
+        # semantics), restricted to the held experts' columns.
+        combine = jnp.einsum("tk,tke->te", gate_vals, onehot)
+        combine = combine[:, first:first + held]
+        y = _held_experts(p, xf, combine)
+        return y.reshape(b, s, d).astype(x.dtype), aux, picks
 
 
 # ---------------------------------------------------------------------------
@@ -791,6 +1038,10 @@ class ModelApply:
     speculative verify path scores all draft positions in one dispatch
     (DESIGN.md §5.3).  Rows at or beyond ``seg_lens[b]`` are garbage by
     contract, exactly like ``last_valid_slice`` on a parked slot.
+
+    A MoE model's cache carries ``moe_picks_held``: the (token, expert)
+    picks on held experts by the valid tokens of the call that returned it
+    (the serve engine reads it with the tokens it fetches).
 
     ``reset_slots(cache, mask)`` clears per-slot recurrent state (cursor,
     SSM/conv state) for slots where mask is True, so a freed slot can be

@@ -1,7 +1,13 @@
-"""Architecture registry: ``--arch <id>`` -> (config, model builder)."""
+"""Architecture registry: ``--arch <id>`` -> (config, model builder).
+
+``<arch>-ep<n>`` names one chip's share of ``<arch>`` under n-way expert
+parallelism: the router keeps every expert, this chip holds the first n-th
+of them (shard 0 of n), everything else is whole."""
 from __future__ import annotations
 
+import dataclasses
 import importlib
+import re
 
 from repro.configs.base import SHAPES, ModelConfig, ShapeConfig  # noqa: F401
 from repro.models import common as cm
@@ -17,12 +23,21 @@ _CONFIG_MODULES = {
     "mamba2-1.3b": "repro.configs.mamba2_13b",
     "zamba2-2.7b": "repro.configs.zamba2_27b",
     "llama-3.2-vision-90b": "repro.configs.llama32_vision_90b",
+    "mellum2-12b": "repro.configs.mellum2_12b",
 }
 
 ARCHS = tuple(_CONFIG_MODULES)
 
 
 def get_config(arch: str, smoke: bool = False) -> ModelConfig:
+    share = re.fullmatch(r"(.+)-ep(\d+)", arch)
+    if share is not None and share.group(1) in _CONFIG_MODULES:
+        cfg = get_config(share.group(1), smoke)
+        shards = int(share.group(2))
+        if cfg.family != "moe" or cfg.n_experts % shards:
+            raise ValueError(f"{arch}: {cfg.n_experts} experts do not split "
+                             f"{shards} ways")
+        return dataclasses.replace(cfg, expert_shards=shards, expert_shard=0)
     mod = importlib.import_module(_CONFIG_MODULES[arch])
     return mod.SMOKE if smoke else mod.CONFIG
 

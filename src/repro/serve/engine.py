@@ -197,6 +197,26 @@ def resolve_decode_kernel(cfg: ModelConfig) -> str:
     return "xla"
 
 
+def _refuse_window_features(cfg: ModelConfig) -> None:
+    """Sliding-window layers keep a per-slot ring of exactly
+    ``sliding_window`` positions beside the paged pool (DESIGN.md §5.2).
+    What the ring cannot give is refused here, before anything is built:
+    prefix sharing (a ring is private to its slot, so an attached prefix
+    would have no window K/V), speculative decode (a rejected draft's ring
+    row overwrites a position the window still needs) and KV page
+    integrity (its fingerprints cover only the pool's pages)."""
+    refused = [name for name, on in (
+        ("prefix_sharing", cfg.prefix_sharing),
+        ("spec_k", cfg.spec_k > 0),
+        ("kv_integrity", cfg.kv_integrity or cfg.chaos_corrupt_p > 0.0),
+    ) if on]
+    if refused:
+        raise ValueError(
+            f"{cfg.arch} has sliding-window layers, whose per-slot rings do "
+            f"not support {', '.join(refused)}; turn "
+            f"{'it' if len(refused) == 1 else 'them'} off")
+
+
 class ServeEngine:
     """Continuous-batching engine over a fixed pool of request slots.
 
@@ -266,6 +286,12 @@ class ServeEngine:
             "ServeEngine requires moe_dispatch='dense' (ragged slots would "
             "let padding contend for expert capacity under 'sorted')"
         )
+        if cfg.windowed:
+            _refuse_window_features(cfg)
+        # Rows the dense expert dispatch computes per token row of a
+        # dispatch: every held expert, in every layer.
+        self._moe_rows = (cfg.experts_held * cfg.n_layers
+                          if cfg.family == "moe" else 0)
         # Plan for the chip this process runs on: a TPU that no hw entry
         # describes is an error, not a v5e by default.
         self.policy = policy_engine or make_engine(
@@ -429,6 +455,17 @@ class ServeEngine:
             # (every slot's whole table), each x chunk_size.
             "decode_pages_read": 0,
             "decode_pages_dense": 0,
+            # Plain decode chunks of a model with sliding-window layers:
+            # the positions one window layer reads (min(context, window)
+            # per decoded token) and what an unbounded read would take
+            # (the whole context).
+            "decode_window_positions_read": 0,
+            "decode_window_positions_dense": 0,
+            # MoE: (token, expert) picks of valid tokens that land on the
+            # experts held here, and the rows the dense dispatch computes
+            # (dispatch rows x held experts), both summed over layers.
+            "moe_picks_held": 0,
+            "moe_rows_computed": 0,
             "spec_tokens": 0,         # tokens emitted by draft/verify rounds
             "prefix_hits": 0,         # admissions that attached shared pages
             "prefix_hits_fresh": 0,   # ... the fresh-admission subset
@@ -765,7 +802,8 @@ class ServeEngine:
             hist, jnp.where(admitted, full_seg, H), nxt
         )
         hist_len = jnp.where(admitted, full_seg + 1, hist_len)
-        return cache, cur_tok, remaining, tok_idx, hist, hist_len, seeds, nxt
+        return (cache, cur_tok, remaining, tok_idx, hist, hist_len, seeds,
+                nxt, cache.get("moe_picks_held"))
 
     def _chunk_fn(self, params, cache, cur_tok, remaining, tok_idx, hist,
                   hist_len, seeds):
@@ -775,7 +813,9 @@ class ServeEngine:
 
         Only the speculative path consumes the n-gram history, so this
         (non-spec) chunk passes ``hist``/``hist_len`` through untouched —
-        no per-token scatter or carry traffic on the hot loop."""
+        no per-token scatter or carry traffic on the hot loop.  A MoE
+        model's steps also return their picks on held experts, fetched
+        with the tokens."""
 
         def step(carry, _):
             cache, tok, rem, tidx = carry
@@ -788,13 +828,14 @@ class ServeEngine:
             tok = jnp.where(active, nxt, tok)
             tidx = jnp.where(active, tidx + 1, tidx)
             rem = jnp.where(active, rem - 1, rem)
-            return (cache, tok, rem, tidx), (tok, active)
+            return (cache, tok, rem, tidx), (tok, active,
+                                             cache.get("moe_picks_held"))
 
-        (cache, tok, rem, tidx), (toks, actives) = jax.lax.scan(
+        (cache, tok, rem, tidx), (toks, actives, picks) = jax.lax.scan(
             step, (cache, cur_tok, remaining, tok_idx),
             None, length=self.chunk_size,
         )
-        return cache, tok, rem, tidx, hist, hist_len, toks, actives
+        return cache, tok, rem, tidx, hist, hist_len, toks, actives, picks
 
     def _spec_chunk_fn(self, params, cache, cur_tok, remaining, tok_idx,
                        hist, hist_len, seeds):
@@ -1401,11 +1442,15 @@ class ServeEngine:
             self.max_len,
         )
         with span("serve.prefill", rows=self.slots * pad):
-            nxt = self._prefill_wave(wave, pad)
+            nxt, picks = self._prefill_wave(wave, pad)
         self.stats["prefill_rows_computed"] += self.slots * pad
         with span("serve.prefill.sync"):
-            first = np.asarray(nxt)            # host sync: 1 per wave
+            first, picks = jax.device_get((nxt, picks))  # 1 sync per wave
         self.stats["host_syncs"] += 1
+        if self._moe_rows:
+            self.stats["moe_picks_held"] += int(picks)
+            self.stats["moe_rows_computed"] += (self.slots * pad
+                                                * self._moe_rows)
         self.stats["admission_waves"] += 1
         if (self.adaptive is not None and self.adaptive.pinned is None
                 and self.stats["admission_waves"]
@@ -1514,9 +1559,10 @@ class ServeEngine:
         return eff
 
     def _prefill_wave(self, wave: list[tuple[int, Request, np.ndarray]],
-                      pad: int) -> jax.Array:
+                      pad: int) -> tuple[jax.Array, jax.Array | None]:
         """Build the wave's prefill arguments and dispatch the prefill;
-        returns the device array of each slot's first token."""
+        returns the device arrays of each slot's first token and of the
+        wave's MoE picks on held experts (None without MoE)."""
         # The full-prompt history buffer only differs from the prefill
         # buffer when some wave member attached a prefix; otherwise the
         # suffix IS the prompt and one buffer serves both arguments.
@@ -1565,14 +1611,30 @@ class ServeEngine:
         toks_d = jnp.asarray(toks)
         htoks_d = jnp.asarray(htoks) if attached else toks_d
         (self.cache, self.cur_tok, self.remaining, self.tok_idx, self.hist,
-         self.hist_len, self.seeds, nxt) = self._prefill(
+         self.hist_len, self.seeds, nxt, picks) = self._prefill(
             self.params, self.cache, toks_d, jnp.asarray(seg),
             jnp.asarray(start), htoks_d, self.cur_tok,
             self.remaining, jnp.asarray(new_rem), jnp.asarray(new_tidx),
             self.tok_idx, self.hist, self.hist_len, jnp.asarray(new_seeds),
             self.seeds,
         )
-        return nxt
+        return nxt, picks
+
+    def _count_window_reads(self) -> None:
+        """Positions one sliding-window layer reads over this chunk's
+        decode steps, against an unbounded read: each live slot decodes
+        min(chunk, budget left) tokens, the step at cache length L reading
+        min(L + 1, W) keys where full attention reads L + 1."""
+        window = self.cfg.sliding_window
+        read = dense = 0
+        for _, r in self._live():
+            length = len(r.prompt) + len(r.generated) - 1
+            steps = min(self.chunk_size, r.max_new_tokens - len(r.generated))
+            ctx = length + 1 + np.arange(steps)
+            read += int(np.minimum(ctx, window).sum())
+            dense += int(ctx.sum())
+        self.stats["decode_window_positions_read"] += read
+        self.stats["decode_window_positions_dense"] += dense
 
     def _run_chunk(self) -> None:
         if self.paged:
@@ -1581,17 +1643,25 @@ class ServeEngine:
                 (self.page_table[live] >= 0).sum())
             self.stats["decode_pages_dense"] += (
                 self.chunk_size * self.slots * self.pages_per_slot)
+        if self.cfg.windowed:
+            self._count_window_reads()
         with span("serve.decode"):
             (self.cache, self.cur_tok, self.remaining, self.tok_idx,
-             self.hist, self.hist_len, toks, actives) = self._decode_chunk(
+             self.hist, self.hist_len, toks, actives,
+             picks) = self._decode_chunk(
                 self.params, self.cache, self.cur_tok, self.remaining,
                 self.tok_idx, self.hist, self.hist_len, self.seeds,
             )
         with span("serve.decode.sync"):
-            t_np, a_np = jax.device_get((toks, actives))  # 1 sync/chunk
+            # 1 sync/chunk
+            t_np, a_np, picks = jax.device_get((toks, actives, picks))
         self.stats["host_syncs"] += 1
         self.stats["decode_syncs"] += 1
         self.stats["chunks"] += 1
+        if self._moe_rows:
+            self.stats["moe_picks_held"] += int(picks.sum())
+            self.stats["moe_rows_computed"] += (
+                self.chunk_size * self.slots * self._moe_rows)
         with span("serve.emit"):
             for slot, r in self._live():
                 emitted = a_np[:, slot]

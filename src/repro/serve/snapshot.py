@@ -75,10 +75,12 @@ class SnapshotError(RuntimeError):
 
 
 def cfg_fingerprint(cfg) -> dict[str, Any]:
-    """JSON-safe view of every identity-relevant config knob."""
+    """JSON-safe view of every identity-relevant config knob, as a
+    snapshot reads it back (tuples as lists)."""
     import dataclasses
     d = dataclasses.asdict(cfg)
-    return {k: v for k, v in d.items() if k not in _FINGERPRINT_EXCLUDE}
+    return json.loads(json.dumps(
+        {k: v for k, v in d.items() if k not in _FINGERPRINT_EXCLUDE}))
 
 
 def _canonical(payload: dict) -> str:

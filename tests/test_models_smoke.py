@@ -106,13 +106,13 @@ def test_moe_sorted_dispatch_matches_dense():
     cfg_d = ModelConfig(
         arch="t", family="moe", n_layers=1, d_model=32, n_heads=4,
         n_kv_heads=4, d_ff=48, vocab=64, vocab_pad_multiple=64, n_experts=8,
-        top_k=2, capacity_factor=8.0, dtype="float32",
+        experts_per_token=2, capacity_factor=8.0, dtype="float32",
     )
     cfg_s = dc.replace(cfg_d, moe_dispatch="sorted")
     p = cm.moe_init(jax.random.PRNGKey(0), cfg_d)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 32), jnp.float32)
-    yd, auxd = cm.apply_moe(p, x, cfg_d)
-    ys, auxs = cm.apply_moe(p, x, cfg_s)
+    yd, auxd, _ = cm.apply_moe(p, x, cfg_d)
+    ys, auxs, _ = cm.apply_moe(p, x, cfg_s)
     np.testing.assert_allclose(np.asarray(yd), np.asarray(ys),
                                rtol=2e-4, atol=2e-4)
     gd = jax.grad(lambda q: cm.apply_moe(q, x, cfg_d)[0].sum())(p)
@@ -122,5 +122,5 @@ def test_moe_sorted_dispatch_matches_dense():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-3, atol=2e-3)
     # tight capacity drops tokens but stays finite
-    yt, _ = cm.apply_moe(p, x, dc.replace(cfg_s, capacity_factor=0.5))
+    yt = cm.apply_moe(p, x, dc.replace(cfg_s, capacity_factor=0.5))[0]
     assert bool(jnp.isfinite(yt).all())

@@ -77,3 +77,25 @@ def test_decode_attention_compiles_for_v5e(arch, one_chip):
         s((slots,), jnp.int32),
     )
     assert "tpu_custom_call" in hlo
+
+
+def test_window_ring_decode_compiles_for_v5e(one_chip):
+    """Mellum2's sliding layers at the agent cell's deployment: the paged
+    kernel over the stacked window rings (21 layers, 16 slots x 1024
+    positions as 64 pages each), read in place through a layer index."""
+    hq, hkv, d, slots, layers, per_slot = 32, 4, 128, 16, 21, 1024 // PAGE
+
+    def s(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    ppb, splits = ops.paged_plan(PAGE * hkv * d * 2, per_slot)
+    ring = (layers, slots * per_slot, PAGE, hkv * d)
+    hlo = _hlo(
+        lambda q, k, v, pg, ln, layer: paged_decode_attention(
+            q, k, v, pg, ln, layer, splits=splits, pages_per_block=ppb,
+            interpret=False),
+        s((slots, hq, d)), s(ring), s(ring),
+        s((slots, per_slot), jnp.int32), s((slots,), jnp.int32),
+        s((), jnp.int32),
+    )
+    assert "tpu_custom_call" in hlo
