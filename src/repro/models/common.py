@@ -525,9 +525,9 @@ def gather_pages(pool: jnp.ndarray, pages: jnp.ndarray,
     Layout-pure under sharing: the gather depends only on (pool bytes,
     table entries), never on which slot "owns" a page — tables that alias
     the same physical page (prefix sharing, DESIGN.md §5.4) materialize
-    bit-identical rows for the aliased positions, including within the
-    admission dispatch that writes them (the scatter's output pool is the
-    gather's input, so a same-wave sharer reads the owner's fresh K/V)."""
+    bit-identical rows for the aliased positions, whichever dispatch wrote
+    them (a same-wave sharer's prefill runs after its owner's, so it reads
+    the owner's fresh K/V)."""
     N, psz, hd = pool.shape[-3:]
     b, P = pages.shape
     idx = jnp.clip(pages, 0, N - 1)

@@ -19,9 +19,9 @@ loop, not the policy search, is the artifact that must be fast):
 * **Ragged slots** — the cache carries a per-slot ``lengths`` cursor
   vector, so slots free and re-admit independently: finished slots park
   (``seg_lens == 0`` leaves their state untouched) while live slots keep
-  decoding, and freed slots take new prompts mid-stream via a ragged
-  right-padded prefill (`models.common.append_kv` drops padding on the
-  scatter, so mixed-length prompts never cross-contaminate).
+  decoding, and freed slots take new prompts mid-stream: each admitted
+  request is prefilled alone into its slot, right-padded to its own
+  bucket (`models.common.append_kv` drops padding on the scatter).
 * **Donated buffers** — the cache (and the per-slot token/budget vectors)
   are donated to each dispatch, so KV updates are in-place on device.
 * **Paged KV pool** (``cfg.cache_layout == "paged"``, DESIGN.md §5.2) —
@@ -364,8 +364,9 @@ class ServeEngine:
         # consume exactly the accepted prefix).  KV-only families rewind.
         self._spec_replay = "ssm" in self.cache or "conv" in self.cache
         self._reset_slots = self.model.reset_slots
+        self._slot_rows = self._cache_slot_rows()
         self._prefill = jax.jit(
-            self._prefill_fn, donate_argnums=(1, 6, 7, 10, 11, 12, 14)
+            self._prefill_fn, donate_argnums=(1, 7, 8, 11, 12, 13, 15)
         )
         self._decode_chunk = jax.jit(
             self._spec_chunk_fn if self.spec else self._chunk_fn,
@@ -449,7 +450,7 @@ class ServeEngine:
             "admitted_fresh": 0,      # first-time admissions (no tokens yet)
             "readmitted": 0,          # preemption-restore re-admissions
             "prefill_work_tokens": 0,  # suffix tokens actually prefilled
-            "prefill_rows_computed": 0,  # slots x pad rows the waves ran
+            "prefill_rows_computed": 0,  # pad rows the prefills ran
             # Paged decode chunks: pages the paged kernel reads (mapped
             # pages of the decoding slots) and pages the gather path reads
             # (every slot's whole table), each x chunk_size.
@@ -494,6 +495,28 @@ class ServeEngine:
             "snapshots": 0,           # snapshot() calls
             "restores": 0,            # restore() calls completed
         }
+
+    def _cache_slot_rows(self) -> list[tuple[int, int] | None]:
+        """Per cache leaf, in flattening order: the (axis, rows) that hold
+        one slot's state, found where the leaf's shape differs from a
+        one-slot engine's cache (traced, never built), or None for a leaf
+        every slot shares (the paged pool, the MoE picks).  A slot axis
+        gives one row; the window rings' slot-major pages give W /
+        page_size rows (``models.common.ring_table``).  Extras are per
+        slot, on axis 0."""
+        kwargs = {**self._cache_kwargs,
+                  **{k: v[:1] for k, v in self.extras.items()}}
+        one = jax.eval_shape(
+            lambda p: self.model.init_cache(p, batch=1, max_len=self.max_len,
+                                            **kwargs), self.params)
+        leaves, tree = jax.tree_util.tree_flatten(self.cache)
+        rows = []
+        for full, own in zip(leaves, tree.flatten_up_to(one)):
+            axes = [i for i, (a, b) in enumerate(zip(full.shape, own.shape))
+                    if a != b]
+            assert len(axes) <= 1, (full.shape, own.shape)
+            rows.append((axes[0], own.shape[axes[0]]) if axes else None)
+        return rows
 
     def _make_allocator(self) -> PageAllocator:
         """Fresh pool allocator; the chaos wrapper when any injection knob
@@ -744,22 +767,30 @@ class ServeEngine:
             return self.sampler(logits, keys).astype(jnp.int32)
 
     def _hist_append(self, hist, positions, tokens):
-        """Scatter ``tokens`` into per-slot history at ``positions``;
-        out-of-range positions (parked slots pass H) drop."""
+        """Scatter (b, k) ``tokens`` into per-slot history at (b, k)
+        ``positions``; out-of-range positions (parked slots pass H) drop."""
         b = hist.shape[0]
-        return hist.at[jnp.arange(b)[:, None] if positions.ndim == 2
-                       else jnp.arange(b), positions].set(tokens, mode="drop")
+        return hist.at[jnp.arange(b)[:, None], positions].set(
+            tokens, mode="drop")
 
-    def _prefill_fn(self, params, cache, tokens, seg_lens, start_lens,
+    def _prefill_fn(self, params, cache, slot, tokens, seg_len, start_len,
                     hist_toks, cur_tok, remaining, new_remaining,
-                    new_tok_idx, tok_idx, hist, hist_len, new_seeds, seeds):
-        """Ragged admission prefill: reset re-admitted slots, prefill their
-        prompts (seg_lens == 0 parks continuing slots), sample each admitted
-        slot's first token on device, and (re)seed the slot's history /
-        token-index / seed state.
+                    new_tok_idx, tok_idx, hist, hist_len, new_seed, seeds):
+        """Admission prefill of one request into ``slot`` (a traced int32
+        scalar, so one program serves every slot): reset and position the
+        slot, prefill its prompt as a batch of one over the slot's own cache
+        state, sample its first token on device, and (re)seed the slot's
+        history / token-index / seed state.  Nothing of another slot is
+        computed.
 
-        ``start_lens`` is the per-slot attach cursor: 0 for a full prefill,
-        a page-aligned shared-prefix length when the slot rides resident
+        The batch of one is the slot's rows of every cache leaf that has
+        them (``_cache_slot_rows``: cursor, page-table row, window-ring
+        pages, contiguous KV, recurrent and cross-attention state), sliced
+        out and written back; a leaf every slot shares (the paged pool) is
+        passed whole and written in place through the page-table row.
+
+        ``start_len`` is the attach cursor: 0 for a full prefill, a
+        page-aligned shared-prefix length when the slot rides resident
         prefix pages (DESIGN.md §5.4) — ``tokens`` then holds only the
         unshared suffix, positioned (RoPE and scatter) at start + i.
         ``hist_toks`` always carries the FULL prompt, so the n-gram history
@@ -772,38 +803,51 @@ class ServeEngine:
         prompt + emitted, and the sampler key for index m reproduces
         exactly the token the uninterrupted run emitted there — the whole
         bit-identical-restore argument, DESIGN.md §5.5)."""
-        b, pad = tokens.shape
         fpad = hist_toks.shape[1]
         H = hist.shape[1]
-        admitted = seg_lens > 0
+        view = self._slot_view(cache, slot)
         if self._reset_slots is not None:
-            cache = self._reset_slots(cache, admitted)
-        cache = dict(cache)
-        cache["lengths"] = jnp.where(
-            admitted, start_lens, cache["lengths"]
-        ).astype(jnp.int32)
-        logits, cache = self.model.prefill(
-            params, cache, tokens, seg_lens=seg_lens
+            view = self._reset_slots(view, jnp.ones((1,), bool))
+        view["lengths"] = start_len[None]
+        logits, view = self.model.prefill(
+            params, view, tokens, seg_lens=seg_len[None]
         )
-        nxt = self._sample(logits, new_seeds, new_tok_idx)
-        cur_tok = jnp.where(admitted, nxt, cur_tok)
-        remaining = jnp.where(admitted, new_remaining, remaining)
-        seeds = jnp.where(admitted, new_seeds, seeds)
-        tok_idx = jnp.where(admitted, new_tok_idx + 1, tok_idx)
+        cache = self._slot_merge(cache, view, slot)
+        nxt = self._sample(logits, new_seed[None], new_tok_idx[None])
+        cur_tok = cur_tok.at[slot].set(nxt[0])
+        remaining = remaining.at[slot].set(new_remaining)
+        seeds = seeds.at[slot].set(new_seed)
+        tok_idx = tok_idx.at[slot].set(new_tok_idx + 1)
         # History: full-prompt rows land at 0..full-1, the first token at
-        # full; parked slots redirect to H and drop.
-        full_seg = start_lens + seg_lens
-        pos = jnp.broadcast_to(jnp.arange(fpad)[None, :], (b, fpad))
-        pos = jnp.where(
-            admitted[:, None] & (pos < full_seg[:, None]), pos, H
-        )
-        hist = self._hist_append(hist, pos, hist_toks)
-        hist = self._hist_append(
-            hist, jnp.where(admitted, full_seg, H), nxt
-        )
-        hist_len = jnp.where(admitted, full_seg + 1, hist_len)
+        # full; the padding redirects to H and drops.
+        full = start_len + seg_len
+        pos = jnp.arange(fpad)
+        hist = hist.at[slot, jnp.where(pos < full, pos, H)].set(
+            hist_toks[0], mode="drop")
+        hist = hist.at[slot, full].set(nxt[0])
+        hist_len = hist_len.at[slot].set(full + 1)
         return (cache, cur_tok, remaining, tok_idx, hist, hist_len, seeds,
-                nxt, cache.get("moe_picks_held"))
+                nxt, view.get("moe_picks_held"))
+
+    def _slot_view(self, cache, slot):
+        """The cache of a batch of one: ``slot``'s rows of each leaf that
+        has them, every shared leaf whole."""
+        leaves, tree = jax.tree_util.tree_flatten(cache)
+        return tree.unflatten([
+            x if at is None
+            else jax.lax.dynamic_slice_in_dim(x, slot * at[1], at[1], at[0])
+            for x, at in zip(leaves, self._slot_rows)])
+
+    def _slot_merge(self, cache, view, slot):
+        """Write a batch-of-one cache back at ``slot``'s rows; shared
+        leaves are taken from it whole."""
+        leaves, tree = jax.tree_util.tree_flatten(cache)
+        return tree.unflatten([
+            new if at is None
+            else jax.lax.dynamic_update_slice_in_dim(x, new, slot * at[1],
+                                                     at[0])
+            for x, new, at in zip(leaves, jax.tree_util.tree_leaves(view),
+                                  self._slot_rows)])
 
     def _chunk_fn(self, params, cache, cur_tok, remaining, tok_idx, hist,
                   hist_len, seeds):
@@ -1431,26 +1475,20 @@ class ServeEngine:
         # occupancy and the (PlanCache-memoized) decode-attention plan.
         with span("serve.plan"):
             self.decode_plan = self._plan_decode()
-        # Attached slots prefill only their unshared suffix (prefix_tokens
-        # is 0 without sharing), so the pad bucket — and the prefill's
-        # compute — shrinks to the widest *suffix* in the wave.  The
-        # n-gram history still seeds from the FULL prompt via a separate
-        # (cheap, scatter-only) buffer, so drafting under sharing matches
-        # the unshared engine.
-        pad = _pad_bucket(
-            max(len(eff) - r.prefix_tokens for _, r, eff in wave),
-            self.max_len,
-        )
-        with span("serve.prefill", rows=self.slots * pad):
-            nxt, picks = self._prefill_wave(wave, pad)
-        self.stats["prefill_rows_computed"] += self.slots * pad
+        # Each admitted request is prefilled alone, at the bucket of its
+        # own unshared suffix (prefix_tokens is 0 without sharing): a
+        # wave computes the rows of the requests it admits and no others.
+        pads = [_pad_bucket(len(eff) - r.prefix_tokens, self.max_len)
+                for _, r, eff in wave]
+        with span("serve.prefill", rows=sum(pads)):
+            nxt, picks = self._prefill_wave(wave, pads)
+        self.stats["prefill_rows_computed"] += sum(pads)
         with span("serve.prefill.sync"):
             first, picks = jax.device_get((nxt, picks))  # 1 sync per wave
         self.stats["host_syncs"] += 1
         if self._moe_rows:
-            self.stats["moe_picks_held"] += int(picks)
-            self.stats["moe_rows_computed"] += (self.slots * pad
-                                                * self._moe_rows)
+            self.stats["moe_picks_held"] += sum(int(p) for p in picks)
+            self.stats["moe_rows_computed"] += sum(pads) * self._moe_rows
         self.stats["admission_waves"] += 1
         if (self.adaptive is not None and self.adaptive.pinned is None
                 and self.stats["admission_waves"]
@@ -1468,8 +1506,8 @@ class ServeEngine:
             )
         with span("serve.emit"):
             now = time.perf_counter()
-            for _, r, _ in wave:
-                r.generated.append(int(first[r.slot]))
+            for (_, r, _), tok in zip(wave, first):
+                r.generated.append(int(tok[0]))
                 self.stats["prefill_tokens"] += 1
                 if r.ttft_s is None and r.admit_t is not None:
                     # True TTFT: admission -> first token (prefill compute);
@@ -1559,66 +1597,60 @@ class ServeEngine:
         return eff
 
     def _prefill_wave(self, wave: list[tuple[int, Request, np.ndarray]],
-                      pad: int) -> tuple[jax.Array, jax.Array | None]:
-        """Build the wave's prefill arguments and dispatch the prefill;
-        returns the device arrays of each slot's first token and of the
-        wave's MoE picks on held experts (None without MoE)."""
-        # The full-prompt history buffer only differs from the prefill
-        # buffer when some wave member attached a prefix; otherwise the
-        # suffix IS the prompt and one buffer serves both arguments.
-        attached = any(r.prefix_tokens for _, r, _ in wave)
-        toks = np.zeros((self.slots, pad), np.int32)
-        if attached:
-            hpad = _pad_bucket(
-                max(len(eff) for _, _, eff in wave), self.max_len
-            )
-            htoks = np.zeros((self.slots, hpad), np.int32)
-        else:
-            htoks = toks
-        seg = np.zeros((self.slots,), np.int32)
-        start = np.zeros((self.slots,), np.int32)
-        new_rem = np.zeros((self.slots,), np.int32)
-        new_tidx = np.zeros((self.slots,), np.int32)
-        new_seeds = np.zeros((self.slots,), np.int32)
-        for slot, r, eff in wave:
+                      pads: list[int]) -> tuple[list, list]:
+        """Dispatch one prefill per admitted request, in wave order, each
+        of shape (1, its pad bucket), so a same-wave sharer reads its
+        owner's fresh K/V (DESIGN.md §5.4); returns the device arrays of
+        each request's first token and MoE picks on held experts (None
+        without MoE), fetched by the caller in one sync."""
+        if self.paged:
+            # Push the host free-list's view of the page table to device.
+            # The table is tiny; replacing the leaf keeps the jitted prefill
+            # signature layout-independent (donation still applies).
+            self.cache = {**self.cache, "pages": jnp.asarray(self.page_table)}
+        firsts, picks = [], []
+        for (slot, r, eff), pad in zip(wave, pads):
             n = len(eff) - r.prefix_tokens
             # Actual prefill compute demand (suffix tokens only — shared
             # or warm-revived prefixes cost nothing).  Unlike
             # prefill_tokens (emitted first tokens) this measures WORK,
             # which is what the adaptive-vs-static bench compares.
             self.stats["prefill_work_tokens"] += n
-            toks[slot, :n] = eff[r.prefix_tokens:]    # right-pad; drops
-            if attached:
-                htoks[slot, :len(eff)] = eff
-            seg[slot] = n
-            start[slot] = r.prefix_tokens      # page-aligned attach cursor
+            toks = np.zeros((1, pad), np.int32)
+            toks[0, :n] = eff[r.prefix_tokens:]         # right-pad
+            toks_d = jnp.asarray(toks)
+            if r.prefix_tokens:
+                # The n-gram history seeds from the FULL prompt, so
+                # drafting under sharing matches the unshared engine; its
+                # buffer is the bucket of the full prompt.
+                htoks = np.zeros(
+                    (1, _pad_bucket(len(eff), self.max_len)), np.int32)
+                htoks[0, :len(eff)] = eff
+                htoks_d = jnp.asarray(htoks)
+            else:
+                htoks_d = toks_d
             # Restore-aware seeding: a fresh request samples stream index
             # 0 with a full budget; a restored one samples index
             # len(generated) with the unconsumed remainder (its last
             # emitted token is part of the prefill, whose final logits
-            # reproduce the uninterrupted run's next sample).
-            new_rem[slot] = r.max_new_tokens - len(r.generated) - 1
-            new_tidx[slot] = len(r.generated)
-            # Fold arbitrary Python ints (64-bit hashes, negatives) into
-            # int32 range: still a pure function of the request's seed, so
-            # determinism and order-independence are preserved.
-            new_seeds[slot] = (0 if r.seed is None else r.seed) % (2 ** 31)
-        if self.paged:
-            # Push the host free-list's view of the page table to device.
-            # The table is tiny; replacing the leaf keeps the jitted prefill
-            # signature layout-independent (donation still applies).
-            self.cache = {**self.cache, "pages": jnp.asarray(self.page_table)}
-        toks_d = jnp.asarray(toks)
-        htoks_d = jnp.asarray(htoks) if attached else toks_d
-        (self.cache, self.cur_tok, self.remaining, self.tok_idx, self.hist,
-         self.hist_len, self.seeds, nxt, picks) = self._prefill(
-            self.params, self.cache, toks_d, jnp.asarray(seg),
-            jnp.asarray(start), htoks_d, self.cur_tok,
-            self.remaining, jnp.asarray(new_rem), jnp.asarray(new_tidx),
-            self.tok_idx, self.hist, self.hist_len, jnp.asarray(new_seeds),
-            self.seeds,
-        )
-        return nxt, picks
+            # reproduce the uninterrupted run's next sample).  Arbitrary
+            # Python ints (64-bit hashes, negatives) fold into int32 range:
+            # still a pure function of the request's seed, so determinism
+            # and order-independence are preserved.
+            (self.cache, self.cur_tok, self.remaining, self.tok_idx,
+             self.hist, self.hist_len, self.seeds, nxt, pk) = self._prefill(
+                self.params, self.cache, np.int32(slot), toks_d,
+                np.int32(n), np.int32(r.prefix_tokens), htoks_d,
+                self.cur_tok, self.remaining,
+                np.int32(r.max_new_tokens - len(r.generated) - 1),
+                np.int32(len(r.generated)), self.tok_idx, self.hist,
+                self.hist_len,
+                np.int32((0 if r.seed is None else r.seed) % (2 ** 31)),
+                self.seeds,
+            )
+            firsts.append(nxt)
+            picks.append(pk)
+        return firsts, picks
 
     def _count_window_reads(self) -> None:
         """Positions one sliding-window layer reads over this chunk's

@@ -291,7 +291,9 @@ def test_window_and_moe_counters():
         np.minimum(ctx + 1, W).sum())
     assert st["decode_window_positions_dense"] == int((ctx + 1).sum())
     assert st["moe_picks_held"] == (6 + 12) * k * L
-    rows = (2 * 8 + st["chunks"] * eng.chunk_size * 2) * cfg.experts_held * L
+    # The wave prefills the one request at its own bucket (8), not both
+    # slots; each decode step runs both slots.
+    rows = (8 + st["chunks"] * eng.chunk_size * 2) * cfg.experts_held * L
     assert st["moe_rows_computed"] == rows
     assert st["host_syncs"] == st["admission_waves"] + st["chunks"]
 

@@ -2,6 +2,7 @@
 tick, read back from the profiler's trace, the prefill-rows counter beside
 them, and the named scopes the decode chunk carries into its ops."""
 import dataclasses
+import functools
 import glob
 
 import jax
@@ -105,9 +106,10 @@ def test_prefill_rows_counter_matches_the_waves(traced):
     stats = eng.serve_stats()
     assert stats["prefill_rows_computed"] == sum(rows)
     assert stats["prefill_rows_computed"] >= stats["prefill_work_tokens"]
-    # Wave 1 pads A's 20 tokens, wave 2 C's 7-token suffix, all slots.
-    pads = [engine_mod._pad_bucket(20, 64), engine_mod._pad_bucket(7, 64)]
-    assert rows == [eng.slots * p for p in pads]
+    # Wave 1 prefills A's 20 tokens and B's 11, each at its own bucket;
+    # wave 2 C's 7-token suffix.  No other slot's rows are computed.
+    pad = functools.partial(engine_mod._pad_bucket, cap=64)
+    assert rows == [pad(20) + pad(11), pad(7)]
 
 
 def test_unknown_span_name_is_refused():
