@@ -1,5 +1,5 @@
 """Serve a small model with batched requests through the device-resident
-continuous-batching engine, with the engine's KV policy decisions printed.
+continuous-batching engine.
 
 The first run through the engine pays jit compilation for the prefill and
 the chunked decode loop; timing that run reports compile time, not serving
@@ -48,7 +48,6 @@ import time
 import jax
 import numpy as np
 
-from repro.core import make_engine
 from repro.models import build_model, get_config
 from repro.serve.engine import Request, ServeEngine
 
@@ -65,11 +64,6 @@ def main():
     cfg = get_config("qwen2.5-32b", smoke=True)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-
-    engine = make_engine()
-    kv_bytes = 2 * cfg.n_kv_heads * cfg.head_dim_ * 64 * 2
-    print(f"KV policy for {kv_bytes}B/layer cache:",
-          engine.kv_policy(kv_bytes).value)
 
     serve = ServeEngine(cfg, params, batch_slots=4, max_len=64, chunk_size=8)
     rng = np.random.default_rng(0)

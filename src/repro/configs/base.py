@@ -98,12 +98,6 @@ class ModelConfig:
     # the gathered dense view; the bit-identity reference for the paged
     # path.
     decode_kernel: str = "auto"   # | "xla" | "pallas_gather" | "pallas_paged"
-    # Split-K parallelism for the Pallas decode kernels.  0 = planned: the
-    # serve engine bakes its plan in here before building the model (jitted
-    # traces need a static split count) — the chip's cores for the paged
-    # kernel, its CachePolicyEngine decode plan for the dense one; direct
-    # model users get kernels.decode_attention.ops' defaults.
-    decode_splits: int = 0
     # Prefix sharing (serving, DESIGN.md §5.4): admission attaches a new
     # request to already-resident full prefix pages via the host-side radix
     # trie (serve.prefix) and refcounted page pool, prefilling only the
@@ -156,7 +150,7 @@ class ModelConfig:
     # acceptance, recompute cost) drive warm-prefix retention beyond
     # refcount zero (bounded by warm_pages), cost-aware preemption victim
     # selection, and per-workload-class policy selection through the
-    # core.sweep exact lattice argmin, re-planned every
+    # serve.adaptive exact lattice argmin, re-planned every
     # adaptive_replan_every admission waves.  Placement-only: every
     # decision moves pages/slots, never tokens — outputs stay
     # bit-identical to the static engine, so snapshot config fingerprints

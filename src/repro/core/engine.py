@@ -19,7 +19,7 @@ from __future__ import annotations
 import dataclasses
 
 from repro import hw
-from repro.core import allocator, remat
+from repro.core import allocator
 from repro.core.planner import PlanCache, Planner
 from repro.core.policy import (
     Assignment,
@@ -30,6 +30,7 @@ from repro.core.policy import (
     static_assignment,
 )
 from repro.core.predictor import PolicyPredictor
+from repro.models import remat
 
 
 @dataclasses.dataclass

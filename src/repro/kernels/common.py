@@ -29,8 +29,9 @@ def interpret_mode(interpret: bool | None = None) -> bool:
 
 def compiler_params(*dimension_semantics: str) -> pltpu.CompilerParams:
     """Mosaic parameters shared by every kernel: the scoped-VMEM limit is
-    the planner's own budget (``hw.Chip.vmem_budget`` of the process's
-    device), so tiles the cost model plans are tiles the compiler admits."""
+    the chip's budget (``hw.Chip.vmem_budget`` of the process's device),
+    the one the kernels' block rules plan against, so the tiles they plan
+    are tiles the compiler admits."""
     return pltpu.CompilerParams(
         dimension_semantics=dimension_semantics or None,
         vmem_limit_bytes=hw.chip_for_device(jax.devices()[0]).vmem_budget,

@@ -1,14 +1,10 @@
 """Public wrappers for decode attention: block and split planning.
 
-The paged kernel plans from what it can observe (:func:`paged_plan`): its
-compute block from the page's bytes and VMEM, its splits from the chip's
-cores.  For the dense kernel the split count is a policy decision: more splits means more parallelism on
-the zero-reuse KV stream but more partial (acc, m, l) write-through traffic
-— exactly the STREAM-output trade-off the cost model prices.  When a
-``CachePolicyEngine`` is passed, its (PlanCache-memoized) plan for the
-decode-shaped attention op supplies the target: one split per planned KV
-block, so the grid parallelism tracks the same lattice argmin the serve
-tier plans with (``ServeEngine.decode_plan`` flows through here).
+Both kernels plan from what they can observe: the shapes they receive and
+the chip this process runs on.  One split rule serves both
+(:func:`split_count`): one split per padded grid block, at most the chip's
+TensorCores.  The paged kernel's compute block comes from the page's bytes
+and VMEM (:func:`paged_plan`); the dense kernel's is at most 512 positions.
 """
 from __future__ import annotations
 
@@ -16,42 +12,12 @@ import math
 
 import jax.numpy as jnp
 
-from repro.core import CachePolicyEngine
-from repro.core.characterize import attention_op
 from repro.kernels.common import cdiv
 
 # K bytes per compute block the paged kernel aims at (pages_per_block).
 BLOCK_K_BYTES = 512 * 1024
-
-
-def plan_splits(
-    s: int,
-    bkv: int,
-    target_parallelism: int = 8,
-    *,
-    plan=None,
-) -> int:
-    """Enough splits to feed the cores without drowning in partials.
-
-    ``blocks`` counts the padded grid's KV blocks (cdiv — a 513-token
-    stream over 512-wide blocks runs 2 grid steps, not 1), so the split
-    count never exceeds the real parallelism available.  ``plan`` (a
-    ``core.allocator.KernelPlan``) overrides the default target with the
-    engine's own block decision: one split per engine-planned KV block.
-    """
-    blocks = max(1, cdiv(s, bkv))
-    if plan is not None:
-        planned_bkv = int(plan.block.get("bkv", bkv)) or bkv
-        target_parallelism = max(1, cdiv(s, planned_bkv))
-    return max(1, min(target_parallelism, blocks))
-
-
-def _engine_plan(engine: CachePolicyEngine, b, hq, hkv, s, d):
-    """The engine's plan for a decode-shaped attention op (sq == 1), via
-    the engine's own PlanCache — repeat calls are hits, not re-sweeps."""
-    return engine.plan_op(attention_op(
-        b, hq, max(1, hkv), 1, s, d, causal=False, name="decode_attention",
-    ))
+# KV positions per compute block of the dense kernel.
+DENSE_BKV = 512
 
 
 def decode_attention(
@@ -61,27 +27,22 @@ def decode_attention(
     lengths: jnp.ndarray | None = None,
     *,
     scale: float | None = None,
-    engine: CachePolicyEngine | None = None,
     bkv: int | None = None,
     splits: int | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
+    """Dense split-KV decode attention over a (b, hkv, s, d) view.  The
+    block is ``DENSE_BKV`` positions and the splits :func:`split_count`'s
+    unless given."""
     from repro.kernels.decode_attention.decode_attention import (
         decode_attention as _kernel,
     )
 
     s = k.shape[2]
-    bkv = bkv or 512
-    if splits is None:
-        plan = None
-        if engine is not None:
-            plan = _engine_plan(
-                engine, q.shape[0], q.shape[1], k.shape[1], s, q.shape[2]
-            )
-        splits = plan_splits(s, bkv, plan=plan)
+    bkv = min(bkv or DENSE_BKV, s)
     return _kernel(
-        q, k, v, lengths, scale=scale, bkv=min(bkv, s), splits=splits,
-        interpret=interpret,
+        q, k, v, lengths, scale=scale, bkv=bkv,
+        splits=splits or split_count(s, bkv), interpret=interpret,
     )
 
 
@@ -97,6 +58,13 @@ def split_cores() -> int:
     """TensorCores of this process's chip: how many splits can run at once.
     A split beyond that only adds partial writes and a merge."""
     return _chip().tensorcores
+
+
+def split_count(kv_positions: int, block: int) -> int:
+    """Splits of a slot's KV stream, for either kernel: one per block of
+    the padded grid (cdiv: 513 positions over 512-wide blocks are 2 blocks),
+    at most the chip's TensorCores."""
+    return max(1, min(split_cores(), cdiv(kv_positions, block)))
 
 
 def pages_per_block(page_bytes: int, pages_per_slot: int) -> int:
@@ -124,8 +92,9 @@ def paged_plan(page_bytes: int, pages_per_slot: int,
     planned; the dense kernel with ``bkv = pages * page_size`` and the same
     splits folds the same blocks (the identity reference)."""
     ppb = pages or pages_per_block(page_bytes, pages_per_slot)
-    blocks = cdiv(pages_per_slot, ppb)
-    return ppb, max(1, min(splits or split_cores(), blocks))
+    if splits:
+        return ppb, max(1, min(splits, cdiv(pages_per_slot, ppb)))
+    return ppb, split_count(pages_per_slot, ppb)
 
 
 def paged_decode_attention(

@@ -36,7 +36,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import hw
 from repro.configs.base import SHAPES
-from repro.core.remat import RematPolicy
+from repro.models.remat import RematPolicy
 from repro.distributed import sharding as sh
 from repro.launch import roofline
 from repro.launch.mesh import make_production_mesh

@@ -641,38 +641,31 @@ def _decode_step_kernel(q, kc, vc, kv_len, cfg, pages, layer=None):
     (``pages_per_block x page_size`` positions) and splits, which makes it
     the bit-identity reference for the paged path (see
     kernels/decode_attention).  On a contiguous cache both fall back to the
-    dense kernel over the ring.
+    dense kernel over the ring.  The kernels plan their blocks and splits
+    from the shapes they receive and the chip (``dec_ops.split_count``).
     """
     from repro.kernels.decode_attention import ops as dec_ops
 
     q1 = q[:, 0]                                       # (b, hq, dh)
-    splits = cfg.decode_splits or None
     if pages is not None:
         if cfg.decode_kernel == "pallas_paged":
             out = dec_ops.paged_decode_attention(
-                q1, kc, vc, pages, kv_len, layer, splits=splits
+                q1, kc, vc, pages, kv_len, layer
             )
         else:
             b, hq, dh = q1.shape
             psz, hd = kc.shape[-2:]
             n_pages = pages.shape[1]
-            ppb, splits = dec_ops.paged_plan(
-                psz * hd * kc.dtype.itemsize, n_pages, splits)
+            ppb = dec_ops.pages_per_block(psz * hd * kc.dtype.itemsize,
+                                          n_pages)
             kd, vd = (jnp.swapaxes(gather_pages(x, pages, layer).reshape(
                 b, n_pages * psz, -1, dh), 1, 2) for x in (kc, vc))
-            out = dec_ops.decode_attention(
-                q1, kd, vd, kv_len, bkv=ppb * psz, splits=splits
-            )
+            out = dec_ops.decode_attention(q1, kd, vd, kv_len, bkv=ppb * psz)
     else:
         if layer is not None:
             kc, vc = kc[layer], vc[layer]
-        t = kc.shape[1]
-        bkv = min(512, t)
-        splits = splits or dec_ops.plan_splits(t, bkv)
         out = dec_ops.decode_attention(
-            q1, jnp.swapaxes(kc, 1, 2), jnp.swapaxes(vc, 1, 2), kv_len,
-            bkv=bkv, splits=splits,
-        )
+            q1, jnp.swapaxes(kc, 1, 2), jnp.swapaxes(vc, 1, 2), kv_len)
     return out[:, None]                                # (b, 1, hq, dh)
 
 

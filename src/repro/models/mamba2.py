@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.core.remat import RematPolicy, apply_remat
+from repro.models.remat import RematPolicy, apply_remat
 from repro.kernels.ssd.ssd import ssd_decode_step
 from repro.models import common as cm
 

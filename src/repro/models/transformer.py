@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import FULL, SLIDING, ModelConfig
-from repro.core.remat import RematPolicy, apply_remat
+from repro.models.remat import RematPolicy, apply_remat
 from repro.models import common as cm
 
 

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.core.remat import RematPolicy, apply_remat
+from repro.models.remat import RematPolicy, apply_remat
 from repro.models import common as cm
 
 MAX_DEC_POS = 65536  # learned decoder position table (covers decode_32k)

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.core.remat import RematPolicy, apply_remat
+from repro.models.remat import RematPolicy, apply_remat
 from repro.models import common as cm
 from repro.models import mamba2 as mb
 

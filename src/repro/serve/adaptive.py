@@ -19,7 +19,7 @@ static engine hard-codes:
   youngest-first;
 * **per-workload policy selection** — at re-plan boundaries (every
   ``cfg.adaptive_replan_every`` admission waves) the observed counters
-  feed ``core.sweep.serve_policy_argmin``, an exact argmin over the
+  feed ``serve_policy_argmin``, an exact argmin over the
   (retention fraction x eviction rank x bypass) lattice, picking the
   combo per workload class.
 
@@ -43,7 +43,7 @@ from __future__ import annotations
 import dataclasses
 import zlib
 
-from repro.core.sweep import SERVE_COMBOS, serve_policy_argmin
+import numpy as np
 
 # Bounded last-touch ring: page-level recency is a hint for reclaim
 # ordering, not ground truth, so it is capped (and deliberately NOT
@@ -55,10 +55,89 @@ LAST_TOUCH_RING = 256
 CLASS_SHORT = "short"
 CLASS_NOVEL = "novel"
 
+# The serve-policy lattice, the serve tier's analogue of the paper's
+# per-operand policy lattice: for one workload class, every
+# (warm-retention fraction x eviction rank x bypass) combo is a row, the
+# expected prefill work per arrival is the cost column, and
+# the policy choice is an exact vectorized argmin — the same
+# adaptive-matches-best-static shape the paper establishes for GPU cache
+# policies, applied to KV page retention.  Ties resolve to the FIRST row
+# (np.argmin), so the axis ordering below doubles as the no-signal
+# default: retain the full budget, LRU rank, no bypass — optimistic
+# retention until the counters prove a class is churn.
+
+SERVE_WARM_FRACS = (1.0, 0.5, 0.0)      # descending: row 0 is optimistic
+SERVE_EVICT_RANKS = ("lru", "reuse")    # warm-eviction ordering
+SERVE_BYPASS = (False, True)            # bypass: never retain this class
+
+SERVE_COMBOS = tuple(
+    (wf, rank, byp)
+    for wf in SERVE_WARM_FRACS
+    for rank in SERVE_EVICT_RANKS
+    for byp in SERVE_BYPASS
+)
+
+_SERVE_FEATURE_DEFAULTS = {
+    "prompt_mean": 0.0,       # mean prompt tokens per arrival of the class
+    "shared_tokens": 0.0,     # mean full-page-prefix tokens shareable/arrival
+    "hit_rate": 0.0,          # observed retained-then-reattached rate (0..1)
+    "churn": 0.0,             # observed retained-never-hit rate (0..1)
+    "reuse_signal": 0.0,      # 1.0 when re-arrival intervals were observed
+    "spec_acceptance": 0.0,   # accepted draft tokens per verify round
+    "spec_k": 0,              # draft length (0 = spec off)
+    "warm_budget": 0,         # allocator warm-tier budget, pages
+    "page_size": 1,           # tokens per page
+}
+
+
+def serve_policy_argmin(features: dict) -> tuple[tuple, float]:
+    """Exact argmin over the serve-policy lattice for one workload class.
+
+    ``features`` are the runtime counters this module accumulates
+    (missing keys take the zero-signal defaults above).  The cost column
+    is expected prefill work per arrival, in tokens:
+
+        cost = prompt_mean
+               - p_hit * shared_tokens                  (warm/prefix hits)
+               + churn * retained_pages * page_size * w (dead retention)
+
+    where ``p_hit = hit_rate * min(1, retained_tokens / shared_tokens)``
+    (a chain the budget can't cover can't hit), retention is zero under
+    bypass, the reuse-distance rank halves the churn penalty only when
+    re-arrival intervals were actually observed (no signal -> no edge
+    over LRU, so ties keep the default), and ``w = 1 / (1 +
+    spec_acceptance * spec_k)`` — when speculation is absorbing decode
+    cost, dead retained pages matter less relative to prefill savings.
+    Returns ``(combo, cost)`` with ``combo`` a ``SERVE_COMBOS`` row.
+    Placement-only by construction: the choice moves pages, never
+    tokens.
+    """
+    f = {**_SERVE_FEATURE_DEFAULTS, **features}
+    wf = np.array([c[0] for c in SERVE_COMBOS])
+    reuse_rank = np.array([c[1] == "reuse" for c in SERVE_COMBOS])
+    bypass = np.array([c[2] for c in SERVE_COMBOS])
+
+    retained_tokens = np.where(
+        bypass, 0.0, wf * f["warm_budget"] * f["page_size"]
+    )
+    coverage = np.minimum(
+        1.0, retained_tokens / max(float(f["shared_tokens"]), 1.0)
+    )
+    p_hit = f["hit_rate"] * coverage
+    rank_discount = np.where(reuse_rank & (f["reuse_signal"] > 0), 0.5, 1.0)
+    churn_w = 1.0 / (1.0 + f["spec_acceptance"] * f["spec_k"])
+    cost = (
+        f["prompt_mean"]
+        - p_hit * f["shared_tokens"]
+        + f["churn"] * retained_tokens * churn_w * rank_discount
+    )
+    r = int(np.argmin(cost))
+    return SERVE_COMBOS[r], float(cost[r])
+
 
 @dataclasses.dataclass(frozen=True)
 class ServeCombo:
-    """One row of the serve-policy lattice (``core.sweep.SERVE_COMBOS``)."""
+    """One row of the serve-policy lattice (``SERVE_COMBOS``)."""
 
     warm_frac: float      # fraction of cfg.warm_pages this class may hold
     evict_rank: str       # "lru" | "reuse" — warm reclaim ordering
@@ -267,7 +346,7 @@ class AdaptivePolicy:
 
     def replan(self, engine_stats: dict) -> dict[str, list]:
         """Feed each class's counters through the exact lattice argmin
-        (``core.sweep.serve_policy_argmin``) and install the winning
+        (``serve_policy_argmin``) and install the winning
         combos.  Deterministic: classes visit in sorted key order.
         Returns ``{class: combo_json}`` for ``policy_report()``."""
         spec_rounds = engine_stats.get("spec_rounds", 0)

@@ -1,16 +1,12 @@
 """Device-resident continuous-batching serve engine.
 
-The memory-policy engine drives two serving decisions (DESIGN.md §5):
+Decode KV is a zero-reuse stream (the paper's throughput-sensitive class):
+on the chip the decode step reads it through the split-KV Pallas kernels
+(``kernels.decode_attention``), which plan their blocks and splits from the
+shapes they are traced with and the chip's cores.  The engine only picks
+the path (`resolve_decode_kernel`).
 
-* KV residency per layer (`engine.kv_policy`): decode KV is a zero-reuse
-  stream (the paper's throughput-sensitive class) — STREAM via the
-  split-KV decode kernel; fixed-source caches (whisper enc K/V, vision
-  patch K/V) are RESIDENT (reused every step, fetched once).
-* Split-count planning for flash-decoding (`kernels.decode_attention.ops`),
-  memoized in the PlanCache and re-consulted at every admission wave.
-
-The serving loop itself is built to run at hardware speed (the inference
-loop, not the policy search, is the artifact that must be fast):
+The serving loop is built to run at hardware speed:
 
 * **Chunked on-device decode** — one `lax.scan` dispatch decodes
   ``chunk_size`` tokens for every slot with on-device sampling and
@@ -92,10 +88,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import hw
 from repro.configs.base import ModelConfig
-from repro.core import CachePolicyEngine, make_engine
-from repro.core.characterize import attention_op
 from repro.models import build_model
 from repro.models.common import paged_kv_spec
 from repro.serve import snapshot as snap
@@ -229,14 +222,13 @@ class ServeEngine:
 
     def __init__(self, cfg: ModelConfig, params, batch_slots: int,
                  max_len: int, extras: dict[str, Any] | None = None,
-                 policy_engine: CachePolicyEngine | None = None,
                  chunk_size: int = 8, n_pages: int | None = None,
                  max_queue: int | None = None,
                  journal_path: str | None = None,
                  no_progress_limit: int = 256):
         # Decode-attention path (DESIGN.md §5.2), resolved once from what
         # the engine observes and baked into the config the model is traced
-        # with, as the split count is below.
+        # with.
         cfg = dataclasses.replace(cfg,
                                   decode_kernel=resolve_decode_kernel(cfg))
         self.cfg = cfg
@@ -292,26 +284,6 @@ class ServeEngine:
         # dispatch: every held expert, in every layer.
         self._moe_rows = (cfg.experts_held * cfg.n_layers
                           if cfg.family == "moe" else 0)
-        # Plan for the chip this process runs on: a TPU that no hw entry
-        # describes is an error, not a v5e by default.
-        self.policy = policy_engine or make_engine(
-            chip=hw.chip_for_device(jax.devices()[0]).name
-        )
-        self.kv_residency = self.policy.kv_policy(self._kv_bytes_per_layer())
-        # Decode-attention plan, memoized in the policy engine's PlanCache:
-        # one lattice search + allocation per serve process, a cache hit for
-        # every subsequent admission wave (re-plans are the admission-time
-        # hot path).
-        self.decode_plan = self._plan_decode()
-        # Paged split-KV decode kernel (DESIGN.md §5.2): the engine's
-        # decode plan decides the kernel's split-K parallelism, and jitted
-        # model traces need that count static — so it is baked into the
-        # config the model is built with.  cfg.decode_splits == 0 means
-        # "let the decode plan decide"; an explicit count wins.
-        self.decode_splits = self._decode_kernel_splits()
-        if cfg.decode_kernel != "xla" and cfg.decode_splits == 0:
-            cfg = dataclasses.replace(cfg, decode_splits=self.decode_splits)
-            self.cfg = cfg
         self.model = build_model(cfg)
         self.cache = self.model.init_cache(
             params, batch=batch_slots, max_len=max_len, **self._cache_kwargs
@@ -321,9 +293,6 @@ class ServeEngine:
             # O(1) per slot): fall back to contiguous bookkeeping rather
             # than gating admission on a phantom page pool.
             self.paged = False
-            self.kv_residency = self.policy.kv_policy(
-                self._kv_bytes_per_layer()
-            )
         # Prefix sharing (DESIGN.md §5.4) rides the paged pool: the trie
         # indexes resident full prompt pages and admission attaches new
         # requests to them.  Pure-KV decoder families only — recurrent
@@ -340,7 +309,7 @@ class ServeEngine:
         # Adaptive serve-tier cache policy (DESIGN.md §5.7): runtime
         # counters drive warm prefix retention (bounded by
         # cfg.warm_pages), cost-aware preemption victims, and per-class
-        # policy re-planning through core.sweep's exact lattice argmin.
+        # policy re-planning through serve.adaptive's exact lattice argmin.
         # Placement-only by construction — the static path pays nothing.
         # The warm tier needs re-attachable page KV (paged + prefix
         # sharing); other engines keep victim costing + replans only.
@@ -549,63 +518,28 @@ class ServeEngine:
         """Free-list view (paged only) — delegated to the PageAllocator."""
         return self.allocator.free_pages
 
-    def _kv_bytes_per_layer(self) -> int:
-        """Real per-layer KV footprint, so residency planning sees the bytes
-        actually allocated: the paged pool's n_pages x page_size positions,
-        not the contiguous worst case of slots x max_len."""
-        kv_heads = max(1, self.cfg.n_kv_heads)
-        positions = (self.n_pages * self.page_size if self.paged
-                     else self.slots * self.max_len)
-        return (2 * positions * kv_heads
-                * self.cfg.head_dim_ * hw.dtype_bytes(self.cfg.dtype))
+    def _decode_kernel_plan(self) -> tuple[int, int]:
+        """(KV positions per compute block, splits) of the Pallas decode
+        kernels over this engine's full-attention cache, by the kernels'
+        own rule (``kernels.decode_attention.ops``)."""
+        from repro.kernels.decode_attention import ops
 
-    def _plan_decode(self):
-        if not (self.cfg.n_heads and self.cfg.head_dim_):
-            return None
-        return self.policy.plan_op(attention_op(
-            self.slots, self.cfg.n_heads, max(1, self.cfg.n_kv_heads),
-            1, self.max_len, self.cfg.head_dim_, causal=False,
-            name="serve_decode",
-        ))
-
-    def _decode_kernel_splits(self) -> int:
-        """Split-K parallelism for the Pallas decode kernels unless the
-        config pins an explicit count: for the paged kernel, the chip's
-        cores (a split beyond them only adds partials to merge); for the
-        dense kernel over a contiguous ring, ``decode_plan`` (one split per
-        engine-planned KV block)."""
-        from repro.kernels.decode_attention.ops import paged_plan, plan_splits
-
-        if self.cfg.decode_splits:
-            return self.cfg.decode_splits
         if self.paged:
-            return paged_plan(self._page_bytes(), self.pages_per_slot)[1]
-        return plan_splits(self.max_len, min(512, self.max_len),
-                           plan=self.decode_plan)
-
-    def _page_bytes(self) -> int:
-        return (self.page_size * self.cfg.n_kv_heads * self.cfg.head_dim_
-                * jnp.dtype(self.cfg.dtype).itemsize)
-
-    def _kernel_bkv(self) -> int:
-        """KV positions per compute block of the Pallas decode kernels."""
-        if not self.paged:
-            return min(512, self.max_len)
-        from repro.kernels.decode_attention.ops import paged_plan
-
-        return paged_plan(self._page_bytes(), self.pages_per_slot)[0] \
-            * self.page_size
+            page_bytes = (self.page_size * self.cfg.n_kv_heads
+                          * self.cfg.head_dim_
+                          * jnp.dtype(self.cfg.dtype).itemsize)
+            ppb, splits = ops.paged_plan(page_bytes, self.pages_per_slot)
+            return ppb * self.page_size, splits
+        bkv = min(ops.DENSE_BKV, self.max_len)
+        return bkv, ops.split_count(self.max_len, bkv)
 
     def policy_report(self) -> dict:
-        """Serving-side policy decisions (DESIGN.md §5) + planner counters."""
+        """Serving-side decisions (DESIGN.md §5) and their counters."""
         report = {
-            "kv_bytes_per_layer": self._kv_bytes_per_layer(),
-            "kv_residency": self.kv_residency.value,
             # Effective layout: "contiguous" when a paged request met a
             # cache family with no KV to page (see __init__ fallback).
             "cache_layout": "paged" if self.paged else "contiguous",
             "sampling": self.sampler.mode,
-            "plan_cache": self.policy.plan_stats(),
         }
         if self.spec:
             report["speculative"] = {
@@ -698,20 +632,15 @@ class ServeEngine:
             "snapshots": self.stats["snapshots"],
             "restores": self.stats["restores"],
         }
-        if self.decode_plan is not None:
+        if self.cfg.n_heads and self.cfg.head_dim_:
+            # The decode-step path the model was traced with (resolved at
+            # construction), and the split-K count and compute block the
+            # Pallas kernels plan for it (used when != "xla").
+            bkv, splits = self._decode_kernel_plan()
             report["decode_attention"] = {
-                "assignment": {
-                    k: v.value for k, v in self.decode_plan.assignment.items()
-                },
-                "vmem_bytes": self.decode_plan.vmem_bytes,
-                "grid_order": list(self.decode_plan.grid_order),
-                # The decode-step path the model was traced with (resolved
-                # at construction), the split-K count baked in (the grid
-                # parallelism of the Pallas kernels when != "xla") and
-                # their compute block.
                 "kernel": self.cfg.decode_kernel,
-                "planned_splits": self.decode_splits,
-                "kernel_bkv": self._kernel_bkv(),
+                "planned_splits": splits,
+                "kernel_bkv": bkv,
             }
         return report
 
@@ -1471,10 +1400,6 @@ class ServeEngine:
             if self._chaos or self._strict:
                 self.check_invariants()
             return
-        # Admission consults the policy engine: KV residency for the current
-        # occupancy and the (PlanCache-memoized) decode-attention plan.
-        with span("serve.plan"):
-            self.decode_plan = self._plan_decode()
         # Each admitted request is prefilled alone, at the bucket of its
         # own unshared suffix (prefix_tokens is 0 without sharing): a
         # wave computes the rows of the requests it admits and no others.
@@ -1494,7 +1419,7 @@ class ServeEngine:
                 and self.stats["admission_waves"]
                 % self.adaptive.replan_every == 0):
             # Re-plan boundary: feed the counters through the serve-policy
-            # lattice (core/sweep.py) and install per-class combos.
+            # lattice (serve/adaptive.py) and install per-class combos.
             # Placement-only — outputs are bit-identical either way.
             with span("serve.replan"):
                 self.adaptive.replan(self.stats)

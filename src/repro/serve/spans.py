@@ -16,7 +16,6 @@ NAMES = (
     "serve.admit",          # admission bookkeeping up to the prefill
     "serve.admit.request",  # one admitted request (id, prefix, suffix)
     "serve.preempt",        # one eviction (id of the victim)
-    "serve.plan",           # decode-attention plan at admission
     "serve.prefill",        # prefill arguments and dispatch (rows)
     "serve.prefill.sync",   # host waiting for the wave's first tokens
     "serve.replan",         # adaptive policy re-plan

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.core.remat import RematPolicy
+from repro.models.remat import RematPolicy
 from repro.models import build_model
 from repro.models import common as model_common
 from repro.train import optimizer as opt

@@ -263,3 +263,11 @@ def test_sitekey_encode_roundtrip(m, k, n):
     for o in op.operands:
         key = SiteKey.from_profile(op, o)
         assert SiteKey.decode(key.encode()) == key
+
+
+def test_kv_policy_decision():
+    eng = make_engine()
+    # Tiny per-layer KV (whisper cross K/V scale): resident.
+    assert eng.kv_policy(2 * 1024 * 1024) is Policy.RESIDENT
+    # Multi-GB decode cache: stream.
+    assert eng.kv_policy(4 * 1024**3) is Policy.STREAM

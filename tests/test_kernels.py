@@ -43,15 +43,6 @@ class TestMatmul:
         y2 = kern(a, b, bm=128, bn=128, bk=128, order="nmk")
         np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=1e-5)
 
-    def test_engine_planned(self):
-        from repro.core import make_engine
-        from repro.kernels.matmul.ops import matmul
-
-        a = jnp.ones((200, 300), jnp.float32)
-        b = jnp.ones((300, 100), jnp.float32)
-        y = matmul(a, b, engine=make_engine())
-        np.testing.assert_allclose(np.asarray(y), 300.0, rtol=1e-5)
-
 
 class TestFlashAttention:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -298,54 +289,66 @@ class TestPagedDecodeAttention:
 
 
 class TestDecodeAttentionPlanning:
-    """Regression pins for the ops.py wiring bugs: floor-div split
-    planning, the ignored ``engine`` argument, and the inner kernel's
-    hard-coded interpret=True."""
+    """The decode kernels plan from their shapes and the chip: one split
+    rule for both, and the inner kernels' interpret default."""
 
-    def test_plan_splits_counts_padded_grid_blocks(self):
-        from repro.kernels.decode_attention.ops import plan_splits
+    def test_plan_splits_counts_padded_grid_blocks(self, monkeypatch):
+        from repro.kernels.decode_attention import ops
 
+        assert ops.split_count(4096, 512) == min(ops.split_cores(), 8)
+        monkeypatch.setattr(ops, "split_cores", lambda: 8)
         # s=513, bkv=512: the padded grid runs 2 blocks — floor division
         # said 1 and starved the second block of a split of its own.
-        assert plan_splits(513, 512) == 2
-        assert plan_splits(512, 512) == 1
-        assert plan_splits(4096, 512) == 8
-        assert plan_splits(4097, 512, target_parallelism=16) == 9
+        assert ops.split_count(513, 512) == 2
+        assert ops.split_count(512, 512) == 1
+        assert ops.split_count(1, 512) == 1
+        assert ops.split_count(4096, 512) == 8
+        monkeypatch.setattr(ops, "split_cores", lambda: 16)
+        assert ops.split_count(4097, 512) == 9
+        # The paged kernel counts its blocks of pages the same way.
+        assert ops.paged_plan(0, 65, pages=8) == (8, 9)
+        assert ops.paged_plan(0, 64, pages=8) == (8, 8)
 
-    def test_engine_plan_drives_splits(self):
-        from repro.core import make_engine
-        from repro.core.characterize import attention_op
-        from repro.kernels.decode_attention.ops import plan_splits
-
-        eng = make_engine()
-        plan = eng.plan_op(attention_op(2, 8, 2, 1, 4096, 64, causal=False,
-                                        name="decode_attention"))
-        want = max(1, min((4096 + plan.block["bkv"] - 1)
-                          // plan.block["bkv"], 4096 // 16))
-        assert plan_splits(4096, 16, plan=plan) == want
-
-    def test_engine_argument_is_consulted(self):
+    def test_dense_ring_and_paged_pool_split_alike(self, monkeypatch):
+        """A contiguous ring and a paged pool of the same positions, at the
+        same compute block, take the same split count: the decode step
+        hands both kernels to the one rule, for any number of cores."""
         import types
 
-        from repro.kernels.decode_attention import ops, ref
+        from repro.kernels.decode_attention import decode_attention as kern
+        from repro.kernels.decode_attention import ops
+        from repro.models.common import _decode_step_kernel
 
-        calls = []
+        seen = {}
 
-        def plan_op(op):
-            calls.append(op)
-            return types.SimpleNamespace(block={"bq": 1, "bkv": 64})
+        def recorder(name):
+            def kernel(q, *args, splits, **kwargs):
+                seen[name] = splits
+                return jnp.zeros(q.shape, q.dtype)
+            return kernel
 
-        fake = types.SimpleNamespace(plan_op=plan_op)
-        ks = jax.random.split(jax.random.PRNGKey(11), 3)
-        q = _rand(ks[0], (2, 8, 64), jnp.float32)
-        k = _rand(ks[1], (2, 2, 256, 64), jnp.float32)
-        v = _rand(ks[2], (2, 2, 256, 64), jnp.float32)
-        got = ops.decode_attention(q, k, v, engine=fake)
-        assert len(calls) == 1, "engine plan must be consulted"
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(ref.decode_attention(q, k, v)),
-            **TOL[jnp.float32],
-        )
+        monkeypatch.setattr(kern, "decode_attention", recorder("dense"))
+        monkeypatch.setattr(kern, "paged_decode_attention",
+                            recorder("paged"))
+        b, hq, hkv, d, psz, P = 2, 4, 2, 128, 16, 128   # 2048 positions
+        # 16 KiB pages: 32 pages per paged block, the dense kernel's 512.
+        page_bytes = psz * hkv * d * 4
+        assert ops.pages_per_block(page_bytes, P) * psz == ops.DENSE_BKV
+        q = jnp.zeros((b, 1, hq, d), jnp.float32)
+        ring = jnp.zeros((b, P * psz, hkv, d), jnp.float32)
+        pool = jnp.zeros((b * P, psz, hkv * d), jnp.float32)
+        pages = jnp.arange(b * P, dtype=jnp.int32).reshape(b, P)
+        kv_len = jnp.full((b,), P * psz, jnp.int32)
+        cfg = types.SimpleNamespace(decode_kernel="pallas_paged")
+        got = []
+        for cores in (1, 2, 8):
+            monkeypatch.setattr(ops, "split_cores", lambda: cores)
+            _decode_step_kernel(q, ring, ring, kv_len, cfg, None)
+            _decode_step_kernel(q, pool, pool, kv_len, cfg, pages)
+            assert seen["dense"] == seen["paged"] == ops.split_count(
+                P * psz, ops.DENSE_BKV)
+            got.append(seen["paged"])
+        assert got == [1, 2, 4]
 
     def test_inner_kernels_default_interpret_from_backend(self):
         import inspect

@@ -335,9 +335,12 @@ def test_policy_report_schema_stable():
     eng = ServeEngine(cfg, params, batch_slots=2, max_len=32)
     report = eng.policy_report()
     assert set(report) == {
-        "kv_bytes_per_layer", "kv_residency", "cache_layout", "sampling",
-        "plan_cache", "speculative", "paged_kv", "prefix_sharing",
-        "adaptive", "lifecycle", "integrity", "decode_attention",
+        "cache_layout", "sampling", "speculative", "paged_kv",
+        "prefix_sharing", "adaptive", "lifecycle", "integrity",
+        "decode_attention",
+    }
+    assert set(report["decode_attention"]) == {
+        "kernel", "planned_splits", "kernel_bkv",
     }
     assert report["adaptive"] == {"enabled": False}   # static engine
     assert set(report["lifecycle"]) == {

@@ -419,24 +419,33 @@ def test_decode_kernel_paged_bit_identical_to_gather(arch):
     assert rep["kernel_bkv"] == ppb * eng.page_size
 
 
-def test_decode_kernel_splits_baked_from_plan():
-    """cfg.decode_splits == 0 means the engine bakes its decode plan's
-    split count into the model config (jitted traces need it static); an
-    explicit count wins over the plan."""
+def test_decode_kernel_splits_baked_from_plan(monkeypatch):
+    """The report's split count and block are the decode kernels' own rule
+    for the engine's cache — the paged pool's pages or the contiguous
+    ring's positions against the chip's cores — not a plan of the
+    engine's."""
+    from repro.kernels.decode_attention import ops
+
+    monkeypatch.setattr(ops, "split_cores", lambda: 4)
     cfg = get_config("qwen2.5-32b", smoke=True)
     params = build_model(cfg).init(jax.random.PRNGKey(0))
-    auto = ServeEngine(
+    paged = ServeEngine(
         dataclasses.replace(_paged(cfg), decode_kernel="pallas_paged"),
         params, batch_slots=2, max_len=32,
     )
-    assert auto.cfg.decode_splits == auto.decode_splits >= 1
-    pinned = ServeEngine(
-        dataclasses.replace(_paged(cfg), decode_kernel="pallas_paged",
-                            decode_splits=2),
-        params, batch_slots=2, max_len=32,
-    )
-    assert pinned.decode_splits == 2
-    assert pinned.policy_report()["decode_attention"]["planned_splits"] == 2
+    page_bytes = (paged.page_size * cfg.n_kv_heads * cfg.head_dim_
+                  * jnp.dtype(cfg.dtype).itemsize)
+    ppb, splits = ops.paged_plan(page_bytes, paged.pages_per_slot)
+    assert paged.policy_report()["decode_attention"] == {
+        "kernel": "pallas_paged", "planned_splits": splits,
+        "kernel_bkv": ppb * paged.page_size,
+    }
+    assert splits == ops.split_count(32, ppb * paged.page_size)
+    ring = ServeEngine(dataclasses.replace(cfg, decode_kernel="pallas_gather"),
+                       params, batch_slots=2, max_len=1536)
+    assert ring.policy_report()["decode_attention"] == {
+        "kernel": "pallas_gather", "planned_splits": 3, "kernel_bkv": 512,
+    }
 
 
 @pytest.mark.parametrize("layout,backend,want", [
@@ -569,27 +578,62 @@ def test_paged_falls_back_for_kv_free_families():
 
 
 def test_paged_policy_report_sees_pooled_bytes():
-    """Residency planning must see the pool's real footprint, not the
-    contiguous worst case."""
+    """The report gives the pool's real capacity beside the contiguous
+    worst case it replaces."""
     cfg = get_config("yi-9b", smoke=True)
     params = build_model(cfg).init(jax.random.PRNGKey(6))
-    cont = ServeEngine(cfg, params, batch_slots=4, max_len=32)
     pooled = ServeEngine(_paged(cfg), params, batch_slots=4, max_len=32,
                          n_pages=8)   # 64 positions vs 128 contiguous
-    rc, rp = cont.policy_report(), pooled.policy_report()
-    assert rp["kv_bytes_per_layer"] * 2 == rc["kv_bytes_per_layer"]
+    rp = pooled.policy_report()
     assert rp["paged_kv"]["pool_positions"] == 64
     assert rp["paged_kv"]["contiguous_positions"] == 128
 
 
-def test_kv_policy_decision():
-    from repro.core import Policy, make_engine
+_NO_PLANNER = """
+import dataclasses, sys
+import jax, numpy as np
+from repro.models import build_model, get_config
+from repro.serve.engine import Request, ServeEngine
 
-    eng = make_engine()
-    # Tiny per-layer KV (whisper cross K/V scale): resident.
-    assert eng.kv_policy(2 * 1024 * 1024) is Policy.RESIDENT
-    # Multi-GB decode cache: stream.
-    assert eng.kv_policy(4 * 1024**3) is Policy.STREAM
+cfg = dataclasses.replace(get_config(sys.argv[1], smoke=True),
+                          cache_layout="paged", kv_page_size=8)
+if cfg.family == "dense":
+    cfg = dataclasses.replace(cfg, prefix_sharing=True, adaptive=True,
+                              warm_pages=2)
+extras = {}
+if cfg.family == "encdec":
+    extras["frames"] = np.zeros((2, cfg.enc_seq, cfg.d_model), np.float32)
+params = build_model(cfg).init(jax.random.PRNGKey(0))
+eng = ServeEngine(cfg, params, batch_slots=2, max_len=32, chunk_size=4,
+                  extras=extras)
+req = Request(prompt=np.arange(5, dtype=np.int32), max_new_tokens=3)
+eng.run([req])
+assert len(req.generated) == 3 and eng.policy_report()["adaptive"]["enabled"] \
+    == (cfg.family == "dense")
+print(sorted(m for m in sys.modules
+             if m == "repro.core" or m.startswith("repro.core.")))
+"""
+
+
+@pytest.mark.parametrize("arch", ["qwen2.5-32b", "zamba2-2.7b",
+                                  "whisper-small"])
+def test_serving_path_loads_no_planner(arch):
+    """Serving a request loads none of the paper's cache-policy planner
+    (``repro.core``): the kernels plan from shapes and the chip, the
+    adaptive tier argmins its own lattice.  In a fresh process, since
+    other tests import ``repro.core`` into this one."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run(
+        [sys.executable, "-c", _NO_PLANNER, arch], capture_output=True,
+        text=True, timeout=300, cwd=root,
+        env=dict(os.environ, PYTHONPATH=os.path.join(root, "src")),
+    )
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert r.stdout.splitlines()[-1] == "[]"
 
 
 # ---------------------------------------------------------------------------

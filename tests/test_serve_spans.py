@@ -81,8 +81,7 @@ def test_every_step_holds_its_phases(traced):
                 "serve.decode.sync", "serve.emit"} <= inner
         if "serve.prefill" in inner:
             waves += 1
-            assert {"serve.plan", "serve.prefill.sync",
-                    "serve.admit.request"} <= inner
+            assert {"serve.prefill.sync", "serve.admit.request"} <= inner
     assert waves == eng.stats["admission_waves"] == 2
     # Nothing lies outside a step: the engine was only ever stepped.
     assert all(any(_inside(s, [e]) for s in steps) for e in events

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.remat import RematPolicy
+from repro.models.remat import RematPolicy
 from repro.data.pipeline import MemmapLM, Prefetcher, SyntheticLM
 from repro.models import build_model, get_config
 from repro.train import checkpoint as ckpt
